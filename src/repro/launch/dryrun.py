@@ -2,7 +2,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ MUST be the first lines — jax locks the device count on first init.
 # The dry-run (and ONLY the dry-run) runs with 512 placeholder host devices
-# so jax.make_mesh can build the production meshes.
+# so launch.mesh can build the production meshes.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -189,11 +189,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    # cost_analysis() is a dict on current jax but a one-element list of
-    # dicts on older releases; normalize both (and None) to a dict
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):
-        xla_cost = xla_cost[0] if xla_cost else {}
     hlo = compiled.as_text()
     if save_hlo:
         with open(save_hlo, "w") as f:

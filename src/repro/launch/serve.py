@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.data.synthetic import SyntheticConfig, make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_api
 from repro.training.train_step import make_decode_step, make_prefill
 
@@ -88,6 +89,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

@@ -31,6 +31,16 @@ from repro.parallel.sharding import ShardingRules, activation_resolver, param_sp
 from repro.training.optimizer import AdamWConfig
 from repro.training.train_step import init_train_state, make_train_step
 from repro.launch import specs as sp
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh_for
+
+
+def train_runtime(seq_len: int) -> dict:
+    """Execution knobs (``models.common.RUNTIME``) for the train step."""
+    knobs = {"use_flash": False}   # the flash kernel has no backward pass
+    if seq_len > 512:
+        knobs.update(q_chunk=256, ssm_chunk=256, mlstm_chunk=256)
+    return knobs
 
 
 def train_loop(cfg, steps: int, global_batch: int, seq_len: int,
@@ -45,15 +55,10 @@ def train_loop(cfg, steps: int, global_batch: int, seq_len: int,
     opt_cfg = AdamWConfig(learning_rate=lr, warmup_steps=min(20, sched // 10),
                           total_steps=sched)
 
-    n_dev = len(jax.devices())
     if mesh is None:
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
-    rules = ShardingRules(mesh=mesh, fsdp=n_dev > 1)
-    if jax.default_backend() == "tpu":
-        # route attention through the Pallas kernels on real hardware
-        cc.RUNTIME.update(use_flash=True, q_chunk=0)
-    elif seq_len > 512:
-        cc.RUNTIME.update(q_chunk=256, ssm_chunk=256, mlstm_chunk=256)
+        mesh = make_mesh_for(len(jax.devices()))
+    rules = ShardingRules(mesh=mesh, fsdp=mesh.size > 1)
+    cc.RUNTIME.update(train_runtime(seq_len))
 
     state = init_train_state(cfg, jax.random.PRNGKey(seed), opt_cfg)
     state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -116,6 +121,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

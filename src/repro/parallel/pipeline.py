@@ -20,12 +20,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map
 
 PyTree = Any
 
@@ -77,12 +73,8 @@ def gpipe_forward(stage_fn: Callable, mesh: Mesh, axis: str,
     in_specs = (P(axis), P())        # params stacked over stages; x replicated
     out_specs = P()
 
-    try:
-        return shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # pre-rename jax: check_vma was called check_rep
-        return shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def gpipe_loss(stage_fn: Callable, loss_fn: Callable, mesh: Mesh, axis: str,

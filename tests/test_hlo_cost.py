@@ -29,8 +29,6 @@ def test_scan_flops_multiplied():
     def one(x, w):
         return x @ w
     xla = jax.jit(one).lower(x, w).compile().cost_analysis()
-    if isinstance(xla, (list, tuple)):  # older jax wraps the dict in a list
-        xla = xla[0]
     assert abs(float(xla["flops"]) * 7 - res["flops"]) / res["flops"] < 1e-6
 
 

@@ -90,7 +90,7 @@ def test_capacity_error_raised(gnn_small):
 @pytest.mark.parametrize("seed", range(8))
 def test_fit_axes_always_divides(seed):
     rng = np.random.default_rng(seed)
-    mesh = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     for _ in range(50):
         dim = int(rng.integers(1, 70000))
         axes = tuple(rng.permutation(["pod", "data", "model"]))

@@ -12,8 +12,8 @@ from repro.models.registry import get_api
 from repro.parallel.sharding import (DEFAULT_ACT_RULES, ShardingRules,
                                      _fit_axes, param_specs)
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _param_structs(arch):
