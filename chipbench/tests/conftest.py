@@ -1,0 +1,13 @@
+"""The benchmark's own tests, run on the CPU: ``python -m pytest chipbench/tests``.
+
+They never turn on the persistent compilation cache and never need a chip.
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
