@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.data.synthetic import SyntheticConfig, make_batch
@@ -29,7 +30,14 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
     cache pytree per token (the caches dominate serving memory:
     B x max_len x layers). ``stats`` is machine-readable so harnesses
     (benchmarks/serve_bench.py) can calibrate simulated replica costs from a
-    real measured decode rate instead of parsing log lines."""
+    real measured decode rate instead of parsing log lines. ``decode_s`` is
+    ``dispatch_s``, the host's loop over the decode steps, plus ``drain_s``,
+    the wait for the device to finish them.
+
+    Four profiler annotations, ``launch.serve.prefill``,
+    ``launch.serve.decode.dispatch``, ``launch.serve.decode.drain`` and
+    ``launch.serve.gather``, mark the phases in a profiler's trace on the
+    device's clock; with no profiler collecting they cost a check each."""
     if jax.default_backend() == "tpu":
         from repro.models import common as cc
         cc.RUNTIME["use_flash"] = True   # Pallas flash/decode kernels
@@ -42,22 +50,30 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
     extra = cfg.n_patches if cfg.family == "vlm" else 0
     max_len = extra + s + gen_tokens
 
-    t0 = time.time()
-    last_logits, caches = jax.jit(prefill_fn, static_argnums=(2,))(
-        params, batch, max_len)
-    token = jnp.argmax(last_logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-    jax.block_until_ready(token)
-    t_prefill = time.time() - t0
+    t0 = time.perf_counter()
+    with TraceAnnotation("launch.serve.prefill"):
+        last_logits, caches = jax.jit(prefill_fn, static_argnums=(2,))(
+            params, batch, max_len)
+        token = jnp.argmax(last_logits[:, -1], axis=-1).astype(
+            jnp.int32)[:, None]
+        jax.block_until_ready(token)
+    t_prefill = time.perf_counter() - t0
 
     out = [token]
-    t0 = time.time()
-    for i in range(gen_tokens - 1):
-        pos = jnp.int32(extra + s + i)
-        token, caches = decode_fn(params, token, pos, caches)
-        out.append(token)
-    jax.block_until_ready(token)
-    t_decode = time.time() - t0
-    gen = jnp.concatenate(out, axis=1)
+    t0 = time.perf_counter()
+    with TraceAnnotation("launch.serve.decode.dispatch"):
+        for i in range(gen_tokens - 1):
+            pos = jnp.int32(extra + s + i)
+            token, caches = decode_fn(params, token, pos, caches)
+            out.append(token)
+    t1 = time.perf_counter()
+    with TraceAnnotation("launch.serve.decode.drain"):
+        jax.block_until_ready(token)
+    t2 = time.perf_counter()
+    t_dispatch, t_drain = t1 - t0, t2 - t1
+    t_decode = t_dispatch + t_drain
+    with TraceAnnotation("launch.serve.gather"):
+        gen = np.asarray(jnp.concatenate(out, axis=1))
     decode_steps = gen_tokens - 1
     stats = {
         "batch": b,
@@ -67,6 +83,8 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
         "prefill_tokens": b * s,
         "prefill_tokens_per_s": b * s / max(t_prefill, 1e-9),
         "decode_s": t_decode,
+        "dispatch_s": t_dispatch,
+        "drain_s": t_drain,
         "decode_steps": decode_steps,
         "decode_tokens": b * decode_steps,
         "tokens_per_s": b * decode_steps / max(t_decode, 1e-9),
@@ -76,7 +94,7 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
     log(f"prefill {s} toks x{b}: {t_prefill:.2f}s; "
         f"decode {decode_steps} steps: {t_decode:.2f}s "
         f"({stats['tokens_per_s']:.1f} tok/s)")
-    return np.asarray(gen), stats
+    return gen, stats
 
 
 def main(argv=None):

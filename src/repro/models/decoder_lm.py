@@ -97,79 +97,89 @@ def layer_cache_init(layer: LayerSpec, cfg: ModelConfig, batch: int,
 def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
                want_cache: bool, max_len: int):
     """Full-sequence layer. Returns (x, aux, cache_or_None)."""
-    h = apply_norm(p["norm1"], x, cfg.norm)
-    # Pin the sequence-parallel boundary to the (low-precision) norm OUTPUT:
-    # without this, GSPMD hoists the seq all-gather above the norm's f32
-    # upcast and the boundary collective moves 2x the bytes (SSPerf H1).
-    h = logical_constraint(h, cc.BATCH, cc.SEQ, cc.EMBED)
+    with jax.named_scope("norm"):
+        h = apply_norm(p["norm1"], x, cfg.norm)
+        # Pin the sequence-parallel boundary to the (low-precision) norm
+        # OUTPUT: without this, GSPMD hoists the seq all-gather above the
+        # norm's f32 upcast and the boundary collective moves 2x the bytes
+        # (SSPerf H1).
+        h = logical_constraint(h, cc.BATCH, cc.SEQ, cc.EMBED)
     cache = None
-    if layer.kind == "attn":
-        if want_cache:
-            y, cache = attn_mod.attn_prefill(p["attn"], layer.attn, h,
-                                             positions, max_len)
-        else:
-            y = attn_mod.attn_full(p["attn"], layer.attn, h, positions)
-    elif layer.kind == "mla":
-        if want_cache:
-            y, cache = attn_mod.mla_prefill(p["mla"], layer.mla, h, positions,
-                                            max_len)
-        else:
-            y = attn_mod.mla_full(p["mla"], layer.mla, h, positions)
-    elif layer.kind == "mamba":
-        if want_cache:
-            y, cache = ssm_mod.mamba_prefill(p["mamba"], layer.mamba, h)
-        else:
-            y = ssm_mod.mamba_full(p["mamba"], layer.mamba, h)
-    elif layer.kind == "mlstm":
-        if want_cache:
-            y, cache = xlstm_mod.mlstm_prefill(p["mlstm"], layer.xlstm, h)
-        else:
-            y = xlstm_mod.mlstm_full(p["mlstm"], layer.xlstm, h)
-    elif layer.kind == "slstm":
-        if want_cache:
-            y, cache = xlstm_mod.slstm_prefill(p["slstm"], layer.xlstm, h)
-        else:
-            y = xlstm_mod.slstm_full(p["slstm"], layer.xlstm, h)
-    x = x + checkpoint_name(y, "block_out")
+    with jax.named_scope("attn"):
+        if layer.kind == "attn":
+            if want_cache:
+                y, cache = attn_mod.attn_prefill(p["attn"], layer.attn, h,
+                                                 positions, max_len)
+            else:
+                y = attn_mod.attn_full(p["attn"], layer.attn, h, positions)
+        elif layer.kind == "mla":
+            if want_cache:
+                y, cache = attn_mod.mla_prefill(p["mla"], layer.mla, h,
+                                                positions, max_len)
+            else:
+                y = attn_mod.mla_full(p["mla"], layer.mla, h, positions)
+        elif layer.kind == "mamba":
+            if want_cache:
+                y, cache = ssm_mod.mamba_prefill(p["mamba"], layer.mamba, h)
+            else:
+                y = ssm_mod.mamba_full(p["mamba"], layer.mamba, h)
+        elif layer.kind == "mlstm":
+            if want_cache:
+                y, cache = xlstm_mod.mlstm_prefill(p["mlstm"], layer.xlstm, h)
+            else:
+                y = xlstm_mod.mlstm_full(p["mlstm"], layer.xlstm, h)
+        elif layer.kind == "slstm":
+            if want_cache:
+                y, cache = xlstm_mod.slstm_prefill(p["slstm"], layer.xlstm, h)
+            else:
+                y = xlstm_mod.slstm_full(p["slstm"], layer.xlstm, h)
+        x = x + checkpoint_name(y, "block_out")
     aux = jnp.zeros((), jnp.float32)
-    if layer.mlp == "dense":
-        h2 = apply_norm(p["norm2"], x, cfg.norm)
-        h2 = logical_constraint(h2, cc.BATCH, cc.SEQ, cc.EMBED)
-        y2 = mlp_mod.mlp(p["mlp"], h2, cfg.act)
-        x = x + checkpoint_name(y2, "block_out")
-    elif layer.mlp == "moe":
-        h2 = apply_norm(p["norm2"], x, cfg.norm)
-        h2 = logical_constraint(h2, cc.BATCH, cc.SEQ, cc.EMBED)
-        y2, aux = mlp_mod.moe(p["moe"], layer.moe, h2, cfg.act,
-                              seq_chunk=cfg.moe_seq_chunk)
-        x = x + checkpoint_name(y2, "block_out")
+    if layer.mlp in ("dense", "moe"):
+        with jax.named_scope("norm"):
+            h2 = apply_norm(p["norm2"], x, cfg.norm)
+            h2 = logical_constraint(h2, cc.BATCH, cc.SEQ, cc.EMBED)
+        with jax.named_scope("mlp"):
+            if layer.mlp == "dense":
+                y2 = mlp_mod.mlp(p["mlp"], h2, cfg.act)
+            else:
+                y2, aux = mlp_mod.moe(p["moe"], layer.moe, h2, cfg.act,
+                                      seq_chunk=cfg.moe_seq_chunk)
+            x = x + checkpoint_name(y2, "block_out")
     x = logical_constraint(x, cc.BATCH, cc.SEQ, cc.EMBED)
     return x, aux, cache
 
 
 def layer_decode(p, layer: LayerSpec, cfg: ModelConfig, x, pos, cache):
     """Single-token layer step. Returns (x, new_cache)."""
-    h = apply_norm(p["norm1"], x, cfg.norm)
-    if layer.kind == "attn":
-        y, cache = attn_mod.attn_decode(p["attn"], layer.attn, h, pos, cache)
-    elif layer.kind == "mla":
-        y, cache = attn_mod.mla_decode(p["mla"], layer.mla, h, pos, cache,
-                                       absorb=cfg.mla_absorb)
-    elif layer.kind == "mamba":
-        y, cache = ssm_mod.mamba_decode(p["mamba"], layer.mamba, h, cache)
-    elif layer.kind == "mlstm":
-        y, cache = xlstm_mod.mlstm_decode(p["mlstm"], layer.xlstm, h, cache)
-    elif layer.kind == "slstm":
-        y, cache = xlstm_mod.slstm_decode(p["slstm"], layer.xlstm, h, cache)
-    x = x + y
-    if layer.mlp == "dense":
-        x = x + mlp_mod.mlp(p["mlp"], apply_norm(p["norm2"], x, cfg.norm),
-                            cfg.act)
-    elif layer.mlp == "moe":
-        y2, _ = mlp_mod.moe(p["moe"], layer.moe,
-                            apply_norm(p["norm2"], x, cfg.norm), cfg.act,
-                            decode=True)
-        x = x + y2
+    with jax.named_scope("norm"):
+        h = apply_norm(p["norm1"], x, cfg.norm)
+    with jax.named_scope("attn"):
+        if layer.kind == "attn":
+            y, cache = attn_mod.attn_decode(p["attn"], layer.attn, h, pos,
+                                            cache)
+        elif layer.kind == "mla":
+            y, cache = attn_mod.mla_decode(p["mla"], layer.mla, h, pos, cache,
+                                           absorb=cfg.mla_absorb)
+        elif layer.kind == "mamba":
+            y, cache = ssm_mod.mamba_decode(p["mamba"], layer.mamba, h, cache)
+        elif layer.kind == "mlstm":
+            y, cache = xlstm_mod.mlstm_decode(p["mlstm"], layer.xlstm, h,
+                                              cache)
+        elif layer.kind == "slstm":
+            y, cache = xlstm_mod.slstm_decode(p["slstm"], layer.xlstm, h,
+                                              cache)
+        x = x + y
+    if layer.mlp in ("dense", "moe"):
+        with jax.named_scope("norm"):
+            h2 = apply_norm(p["norm2"], x, cfg.norm)
+        with jax.named_scope("mlp"):
+            if layer.mlp == "dense":
+                y2 = mlp_mod.mlp(p["mlp"], h2, cfg.act)
+            else:
+                y2, _ = mlp_mod.moe(p["moe"], layer.moe, h2, cfg.act,
+                                    decode=True)
+            x = x + y2
     return x, cache
 
 
@@ -288,15 +298,17 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
             want_cache: bool = False, max_len: int = 0):
     """tokens: (B,S) int32 (or embeds (B,S,d)). Returns (logits, aux, caches)."""
     if embeds is None:
-        embeds = params["embed"][tokens]
+        with jax.named_scope("embed"):
+            embeds = params["embed"][tokens]
     x = logical_constraint(embeds, cc.BATCH, cc.SEQ, cc.EMBED)
     b, s = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     max_len = max_len or s
     x, aux, caches = backbone_full(params, cfg, x, positions, want_cache,
                                    max_len)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    return _logits(params, cfg, x), aux, caches
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        return _logits(params, cfg, x), aux, caches
 
 
 def _chunked_ce(params, cfg: ModelConfig, x, labels):
@@ -330,13 +342,15 @@ def loss_and_metrics(params, cfg: ModelConfig, batch: dict):
     labels = batch["labels"]
     b, s = batch["tokens"].shape
     if cfg.ce_chunk and s % cfg.ce_chunk == 0 and s > cfg.ce_chunk:
-        embeds = params["embed"][batch["tokens"]]
+        with jax.named_scope("embed"):
+            embeds = params["embed"][batch["tokens"]]
         x = logical_constraint(embeds, cc.BATCH, cc.SEQ, cc.EMBED)
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
                                      (b, s))
         x, aux, _ = backbone_full(params, cfg, x, positions, False, s)
-        x = apply_norm(params["final_norm"], x, cfg.norm)
-        ce = _chunked_ce(params, cfg, x, labels)
+        with jax.named_scope("lm_head"):
+            x = apply_norm(params["final_norm"], x, cfg.norm)
+            ce = _chunked_ce(params, cfg, x, labels)
     else:
         logits, aux, _ = forward(params, cfg, tokens=batch["tokens"])
         mask = (labels >= 0).astype(jnp.float32)
@@ -355,7 +369,8 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None,
 
 def decode_step(params, cfg: ModelConfig, token, pos, caches):
     """token: (B,1) int32; pos: scalar int32. Returns (logits, new_caches)."""
-    x = params["embed"][token]
+    with jax.named_scope("embed"):
+        x = params["embed"][token]
     new_caches = []
     for seg, seg_p, seg_c in zip(cfg.segments, params["segments"], caches):
         if seg.count == 1:
@@ -369,8 +384,9 @@ def decode_step(params, cfg: ModelConfig, token, pos, caches):
 
             x, seg_new = jax.lax.scan(body, x, (seg_p, seg_c))
             new_caches.append(seg_new)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    return _logits(params, cfg, x), new_caches
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        return _logits(params, cfg, x), new_caches
 
 
 def param_count(params) -> int:

@@ -94,7 +94,8 @@ def make_decode_step(cfg: ModelConfig, api: ModelApi | None = None,
 
     def serve_step(params, token, pos, caches):
         logits, new_caches = api.decode_step(params, cfg, token, pos, caches)
-        next_token = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_token = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_token[:, None], new_caches
 
     return serve_step
