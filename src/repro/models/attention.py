@@ -191,31 +191,52 @@ def attn_prefill(p, spec: AttnSpec, x, positions, max_len: int):
     return y, cache
 
 
-def attn_decode(p, spec: AttnSpec, x, pos, cache: dict):
+def cache_slab(buf, layer):
+    """``buf`` itself, or with ``layer`` its slab of a layer-stacked cache
+    (leading ``(count,)`` axis)."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+def cache_put(buf, new, start: tuple, layer):
+    """``dynamic_update_slice`` of ``new`` at ``start``; with ``layer`` into
+    that layer's slab of a layer-stacked ``buf``, so only ``new``'s bytes
+    move."""
+    if layer is None:
+        return jax.lax.dynamic_update_slice(buf, new, start)
+    return jax.lax.dynamic_update_slice(buf, new[None], (layer,) + start)
+
+
+def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
     """One-token decode. x: (B,1,d); pos: scalar int32 (current position).
-    Returns (y, new_cache)."""
+    Returns (y, new_cache).
+
+    ``layer`` (int32 scalar) says ``cache`` is a segment's layer-stacked
+    cache, each leaf with a leading (count,) axis, and this is its layer
+    ``layer``: the new K/V row (and ring slot position) is written in place
+    into the stacked arrays, the layer's slab is read from them as the
+    attention's operand, and the returned cache keeps the stacked shape.
+    ``None`` is one layer's unstacked cache."""
     b = x.shape[0]
-    h, kvh, dh = spec.n_heads, spec.n_kv_heads, spec.head_dim
     positions = jnp.full((b, 1), pos, jnp.int32)
     q, k_new, v_new = _project_qkv(p, spec, x, positions)
 
-    t = cache["k"].shape[1]
+    t = cache["k"].shape[-3]
+    slot = pos if spec.window is None else jnp.mod(pos, t)
+    new_cache = {
+        "k": cache_put(cache["k"], k_new, (0, slot, 0, 0), layer),
+        "v": cache_put(cache["v"], v_new, (0, slot, 0, 0), layer),
+    }
     if spec.window is None:
-        slot = pos
-        k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-        k_pos = jnp.arange(t, dtype=jnp.int32)
-        valid = k_pos <= pos
-        new_cache = {"k": k, "v": v}
+        valid = jnp.arange(t, dtype=jnp.int32) <= pos
     else:
-        slot = jnp.mod(pos, t)
-        k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-        slot_pos = jax.lax.dynamic_update_slice(
-            cache["slot_pos"], jnp.full((1,), pos, jnp.int32), (slot,))
+        new_cache["slot_pos"] = cache_put(
+            cache["slot_pos"], jnp.full((1,), pos, jnp.int32), (slot,), layer)
+        slot_pos = cache_slab(new_cache["slot_pos"], layer)
         valid = (slot_pos >= 0) & (slot_pos <= pos) & (slot_pos > pos - spec.window)
-        k_pos = slot_pos
-        new_cache = {"k": k, "v": v, "slot_pos": slot_pos}
+    k = cache_slab(new_cache["k"], layer)
+    v = cache_slab(new_cache["v"], layer)
 
     if FLAGS["use_flash"]:
         from repro.kernels.decode_attention import ops as dec_ops
@@ -341,17 +362,23 @@ def mla_prefill(p, spec: MLASpec, x, positions, max_len: int):
     return y, cache
 
 
-def mla_decode(p, spec: MLASpec, x, pos, cache: dict, absorb: bool = False):
+def mla_decode(p, spec: MLASpec, x, pos, cache: dict, absorb: bool = False,
+               layer=None):
     """One-token MLA decode. absorb=True uses the matmul-absorbed order
-    (never re-expands K/V for the whole cache — the §Perf variant)."""
+    (never re-expands K/V for the whole cache — the §Perf variant).
+    ``layer`` is as in ``attn_decode``: with it, the new compressed row is
+    written in place into the layer-stacked cache."""
     b = x.shape[0]
     h = spec.n_heads
     positions = jnp.full((b, 1), pos, jnp.int32)
     q_nope, q_rope = _mla_q(p, spec, x, positions)            # (B,1,H,*)
     c_new, r_new = _mla_ckv(p, spec, x, positions)            # (B,1,L),(B,1,R)
-    ckv = jax.lax.dynamic_update_slice(cache["ckv"], c_new, (0, pos, 0))
-    k_rope = jax.lax.dynamic_update_slice(cache["k_rope"], r_new, (0, pos, 0))
-    new_cache = {"ckv": ckv, "k_rope": k_rope}
+    new_cache = {
+        "ckv": cache_put(cache["ckv"], c_new, (0, pos, 0), layer),
+        "k_rope": cache_put(cache["k_rope"], r_new, (0, pos, 0), layer),
+    }
+    ckv = cache_slab(new_cache["ckv"], layer)
+    k_rope = cache_slab(new_cache["k_rope"], layer)
 
     t = ckv.shape[1]
     valid = jnp.arange(t, dtype=jnp.int32) <= pos

@@ -12,7 +12,13 @@ parameters and per-block remat — HLO size and compile time stay flat in depth
 
 Segment parameters are a list (one entry per layer-in-block) of layer param
 dicts; for count > 1 every leaf gains a leading (count,) axis. Caches mirror
-that layout, so they shard with NamedSharding like parameters.
+that layout, so they shard with NamedSharding like parameters. Decode scans a
+count > 1 segment with its stacked caches in the scan's carry and the layer
+index beside the stacked params in its xs: an attention or MLA layer writes
+its new row in place into the stacked arrays and reads its slab from them,
+and a recurrent layer writes its new state slab back, so no layer's cache is
+sliced out and restacked whole. The carry keeps the layout the caches enter
+the step in, so the donated cache is updated where it lies.
 """
 from __future__ import annotations
 
@@ -20,8 +26,10 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import LayerSpec, ModelConfig, Segment
 from repro.models import attention as attn_mod
@@ -150,25 +158,38 @@ def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
     return x, aux, cache
 
 
-def layer_decode(p, layer: LayerSpec, cfg: ModelConfig, x, pos, cache):
-    """Single-token layer step. Returns (x, new_cache)."""
+def layer_decode(p, layer: LayerSpec, cfg: ModelConfig, x, pos, cache,
+                 index=None):
+    """Single-token layer step. Returns (x, new_cache).
+
+    With ``index`` (int32 scalar), ``cache`` is the segment's layer-stacked
+    cache and this layer is its slab ``index``; the returned cache stays
+    stacked. Attention layers append their row in place; recurrent layers
+    replace their whole state slab."""
     with jax.named_scope("norm"):
         h = apply_norm(p["norm1"], x, cfg.norm)
     with jax.named_scope("attn"):
         if layer.kind == "attn":
             y, cache = attn_mod.attn_decode(p["attn"], layer.attn, h, pos,
-                                            cache)
+                                            cache, index)
         elif layer.kind == "mla":
             y, cache = attn_mod.mla_decode(p["mla"], layer.mla, h, pos, cache,
-                                           absorb=cfg.mla_absorb)
-        elif layer.kind == "mamba":
-            y, cache = ssm_mod.mamba_decode(p["mamba"], layer.mamba, h, cache)
-        elif layer.kind == "mlstm":
-            y, cache = xlstm_mod.mlstm_decode(p["mlstm"], layer.xlstm, h,
-                                              cache)
-        elif layer.kind == "slstm":
-            y, cache = xlstm_mod.slstm_decode(p["slstm"], layer.xlstm, h,
-                                              cache)
+                                           absorb=cfg.mla_absorb, layer=index)
+        else:
+            state = jax.tree.map(lambda a: attn_mod.cache_slab(a, index),
+                                 cache)
+            if layer.kind == "mamba":
+                y, state = ssm_mod.mamba_decode(p["mamba"], layer.mamba, h,
+                                                state)
+            elif layer.kind == "mlstm":
+                y, state = xlstm_mod.mlstm_decode(p["mlstm"], layer.xlstm, h,
+                                                  state)
+            elif layer.kind == "slstm":
+                y, state = xlstm_mod.slstm_decode(p["slstm"], layer.xlstm, h,
+                                                  state)
+            cache = state if index is None else jax.tree.map(
+                lambda a, s: attn_mod.cache_put(a, s, (0,) * s.ndim, index),
+                cache, state)
         x = x + y
     if layer.mlp in ("dense", "moe"):
         with jax.named_scope("norm"):
@@ -197,10 +218,11 @@ def block_full(block_p, seg: Segment, cfg: ModelConfig, x, positions,
     return x, aux_sum, caches
 
 
-def block_decode(block_p, block_c, seg: Segment, cfg: ModelConfig, x, pos):
+def block_decode(block_p, block_c, seg: Segment, cfg: ModelConfig, x, pos,
+                 index=None):
     new_caches = []
     for p_i, c_i, layer in zip(block_p, block_c, seg.layers):
-        x, c = layer_decode(p_i, layer, cfg, x, pos, c_i)
+        x, c = layer_decode(p_i, layer, cfg, x, pos, c_i, index)
         new_caches.append(c)
     return x, new_caches
 
@@ -367,6 +389,20 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None,
     return logits[:, -1:], caches
 
 
+def _default_layout(x):
+    """``x`` held to the layout the default device gives an array of its
+    shape: the layout the caches enter and leave the step in. On a TPU, XLA
+    would otherwise give the decode scan's carry the layout its body prefers
+    (the decode kernel's, head dim minor) and copy every stacked cache whole
+    into and out of the loop."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return x
+    pjrt = dev.client.get_default_layout(np.dtype(x.dtype), x.shape, dev)
+    return with_layout_constraint(
+        x, Layout(Layout.from_pjrt_layout(pjrt).major_to_minor))
+
+
 def decode_step(params, cfg: ModelConfig, token, pos, caches):
     """token: (B,1) int32; pos: scalar int32. Returns (logits, new_caches)."""
     with jax.named_scope("embed"):
@@ -377,12 +413,15 @@ def decode_step(params, cfg: ModelConfig, token, pos, caches):
             x, c = block_decode(seg_p, seg_c, seg, cfg, x, pos)
             new_caches.append(c)
         else:
-            def body(h, pc, _seg=seg):
-                p_i, c_i = pc
-                h2, c2 = block_decode(p_i, c_i, _seg, cfg, h, pos)
-                return h2, c2
+            def body(carry, xs, _seg=seg):
+                h, c = carry
+                p_i, idx = xs
+                h, c = block_decode(p_i, c, _seg, cfg, h, pos, idx)
+                return (h, jax.tree.map(_default_layout, c)), None
 
-            x, seg_new = jax.lax.scan(body, x, (seg_p, seg_c))
+            (x, seg_new), _ = jax.lax.scan(
+                body, (x, seg_c),
+                (seg_p, jnp.arange(seg.count, dtype=jnp.int32)))
             new_caches.append(seg_new)
     with jax.named_scope("lm_head"):
         x = apply_norm(params["final_norm"], x, cfg.norm)

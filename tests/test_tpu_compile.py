@@ -117,3 +117,38 @@ def test_train_step_grad_compiles_at_phi3_widths(one_chip, monkeypatch):
     compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=(0,)).lower(
         state, batch).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "starcoder2-3b"])
+def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
+                                                   arch):
+    """The serving decode step at real widths, four stacked layers, batch 8,
+    1280 cache slots, through the compiled decode kernel. The layer scan
+    carries the stacked caches in the layout they enter in, so nothing
+    copies a stacked cache whole into or out of the loop, and the step's
+    scratch memory stays below one stacked cache."""
+    from repro.models import decoder_lm as dlm
+    from repro.training.train_step import make_decode_step
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: list(one_chip.device_set))
+    monkeypatch.setitem(cc.RUNTIME, "use_flash", True)
+    cfg = get_config(arch)
+    cfg = dataclasses.replace(cfg, segments=(
+        Segment(count=4, layers=cfg.segments[0].layers),))
+    place = lambda t: jax.tree.map(                               # noqa: E731
+        lambda s: _spec(s.shape, s.dtype, one_chip), t)
+    params = place(sp.param_struct(cfg))
+    caches = place(jax.eval_shape(lambda: dlm.init_caches(cfg, 8, 1280)))
+    token = _spec((8, 1), jnp.int32, one_chip)
+    pos = _spec((), jnp.int32, one_chip)
+    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(3,)).lower(
+        params, token, pos, caches).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    k = caches[0][0]["k"]
+    stacked = "bf16[" + ",".join(map(str, k.shape)) + "]"
+    copies = [line for line in text.splitlines()
+              if stacked in line.split(" copy", 1)[0]
+              and (" copy(" in line or " copy-start(" in line)]
+    assert not copies, copies[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < k.size * 2
