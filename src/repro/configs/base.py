@@ -36,6 +36,16 @@ class MLASpec:
     qk_rope_dim: int
     v_head_dim: int
     rope_theta: float = 10_000.0
+    # YaRN context extension of the rope dims (DeepSeek-V2's ``rope_scaling``);
+    # factor 1 is plain rope. ``mscale_all_dim`` scales the softmax by
+    # yarn_mscale(factor, mscale_all_dim)**2; cos/sin are scaled by
+    # yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim).
+    yarn_factor: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +56,24 @@ class MoESpec:
     n_shared: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # The experts this layer holds: ``first_local`` .. ``first_local +
+    # n_local - 1`` of the router's ``n_experts`` (0 = all of them). Under
+    # expert parallelism each chip holds a share and computes its own
+    # experts' part of the result; the router always scores all experts.
+    first_local: int = 0
+    n_local: int = 0
+    # Group-limited greedy routing: experts fall into ``n_group`` equal
+    # groups, each token keeps the ``topk_group`` groups with the best
+    # expert, and picks its ``top_k`` among them (1/1 = plain top-k).
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0    # weights of the routed experts are x this
+    norm_topk: bool = True       # renormalise the top-k weights to sum 1
+
+    @property
+    def held(self) -> int:
+        """Number of experts whose weights this layer holds."""
+        return self.n_local or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,7 +139,6 @@ class ModelConfig:
     ce_chunk: int = 0                    # seq-chunked CE loss (0 = off):
                                          # never materializes (B,S,V) logits
     sub_quadratic: bool = False          # arch supports long_500k decode
-    mla_absorb: bool = False             # absorbed MLA decode (perf variant)
     logits_fp32: bool = True
 
     @property
@@ -163,7 +190,9 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
                                   v_head_dim=8) if l.mla else None
         moe = dataclasses.replace(l.moe, n_experts=4,
                                   top_k=min(l.moe.top_k, 2), d_ff_expert=32,
-                                  n_shared=min(l.moe.n_shared, 1)) if l.moe else None
+                                  n_shared=min(l.moe.n_shared, 1),
+                                  n_group=min(l.moe.n_group, 2),
+                                  topk_group=1) if l.moe else None
         mamba = dataclasses.replace(l.mamba, d_state=4) if l.mamba else None
         xl = dataclasses.replace(l.xlstm, n_heads=2) if l.xlstm else None
         return dataclasses.replace(l, attn=attn, mla=mla, moe=moe, mamba=mamba,

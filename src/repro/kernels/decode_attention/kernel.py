@@ -57,14 +57,17 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
 
 def decode_attention_grouped(q, k, v, valid, *,
                              block_kv: int = DEFAULT_BLOCK_KV,
+                             scale: float | None = None,
                              interpret: bool = True):
     """q (B, KV, G, D) — queries grouped by kv head; k/v (B, KV, T, D);
-    valid (1, T) int32. Returns (B, KV, G, D)."""
+    valid (1, T) int32. Returns (B, KV, G, D). ``scale`` multiplies the
+    scores (default D^-0.5)."""
     b, kvh, g, d = q.shape
     t = k.shape[2]
     nk = t // block_kv
     grid = (b, kvh, nk)
-    kernel = functools.partial(_decode_kernel, scale=d ** -0.5)
+    kernel = functools.partial(_decode_kernel,
+                               scale=d ** -0.5 if scale is None else scale)
     return pl.pallas_call(
         kernel,
         grid=grid,

@@ -10,13 +10,15 @@ from repro.kernels.decode_attention import kernel as _k
 from repro.kernels.decode_attention import ref as _ref
 
 
-@functools.partial(jax.jit, static_argnames=("block_kv", "force_ref"))
+@functools.partial(jax.jit, static_argnames=("block_kv", "force_ref",
+                                             "scale"))
 def decode_attention(q, k, v, valid, *, block_kv: int = _k.DEFAULT_BLOCK_KV,
-                     force_ref: bool = False):
+                     force_ref: bool = False, scale: float | None = None):
     """Model layout: q (B, 1, H, D); k/v (B, T, KV, D); valid (T,) bool/int.
-    Returns (B, 1, H, D)."""
+    Returns (B, 1, H, D). ``scale`` multiplies the scores (default
+    D^-0.5)."""
     if force_ref:
-        return _ref.decode_attention_ref(q, k, v, valid)
+        return _ref.decode_attention_ref(q, k, v, valid, scale=scale)
     b, _, h, d = q.shape
     t, kvh = k.shape[1], k.shape[2]
     g = h // kvh
@@ -32,5 +34,5 @@ def decode_attention(q, k, v, valid, *, block_kv: int = _k.DEFAULT_BLOCK_KV,
     qg = q.reshape(b, kvh, g, d)
     interpret = jax.default_backend() != "tpu"
     o = _k.decode_attention_grouped(qg, kt, vt, vmask, block_kv=bk,
-                                    interpret=interpret)
+                                    scale=scale, interpret=interpret)
     return o.reshape(b, 1, h, d)

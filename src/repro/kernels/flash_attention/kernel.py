@@ -72,10 +72,12 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window=None,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_kv: int = DEFAULT_BLOCK_KV,
                          seq_kv: int | None = None,
+                         scale: float | None = None,
                          interpret: bool = True):
     """q (B, H, Sq, D); k/v (B, KV, Skv, D); H % KV == 0. Sq/Skv must be
     multiples of the block sizes (ops.py pads; seq_kv = true unpadded kv
-    length for the padding mask)."""
+    length for the padding mask). ``scale`` multiplies the scores
+    (default D^-0.5)."""
     b, h, sq, d = q.shape
     _, kv, skv, _ = k.shape
     assert h % kv == 0, (h, kv)
@@ -83,7 +85,8 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window=None,
     nq, nk = sq // block_q, skv // block_kv
     grid = (b, h, nq, nk)
     kernel = functools.partial(
-        _flash_kernel, scale=d ** -0.5, causal=causal, window=window,
+        _flash_kernel, scale=d ** -0.5 if scale is None else scale,
+        causal=causal, window=window,
         seq_kv=seq_kv if seq_kv is not None else skv,
         block_q=block_q, block_kv=block_kv)
     return pl.pallas_call(

@@ -22,14 +22,16 @@ def _pad_to(x, axis, mult):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_kv", "force_ref"))
+                                             "block_kv", "force_ref", "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     block_q: int = _k.DEFAULT_BLOCK_Q,
                     block_kv: int = _k.DEFAULT_BLOCK_KV,
-                    force_ref: bool = False):
-    """Public API — model layout: q (B, S, H, D); k/v (B, T, KV, D)."""
+                    force_ref: bool = False, scale: float | None = None):
+    """Public API — model layout: q (B, S, H, D); k/v (B, T, KV, D).
+    ``scale`` multiplies the scores (default D^-0.5)."""
     if force_ref:
-        return _ref.attention_ref(q, k, v, causal=causal, window=window)
+        return _ref.attention_ref(q, k, v, causal=causal, window=window,
+                                  scale=scale)
     b, s, h, d = q.shape
     t = k.shape[1]
     bq = min(block_q, max(8, 1 << (s - 1).bit_length()))
@@ -40,5 +42,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     interpret = jax.default_backend() != "tpu"
     o = _k.flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                                 block_q=bq, block_kv=bk, seq_kv=t,
-                                interpret=interpret)
+                                scale=scale, interpret=interpret)
     return o[:, :, :s].transpose(0, 2, 1, 3)

@@ -17,6 +17,7 @@ from jax.profiler import TraceAnnotation
 from repro.configs import get_config, reduce_for_smoke
 from repro.data.synthetic import SyntheticConfig, make_batch
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models.decoder_lm import has_moe
 from repro.models.registry import get_api
 from repro.training.train_step import make_decode_step, make_prefill
 
@@ -32,7 +33,11 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
     (benchmarks/serve_bench.py) can calibrate simulated replica costs from a
     real measured decode rate instead of parsing log lines. ``decode_s`` is
     ``dispatch_s``, the host's loop over the decode steps, plus ``drain_s``,
-    the wait for the device to finish them.
+    the wait for the device to finish them. A decoder LM with MoE layers
+    adds their counts (``models.mlp.moe_dropless``), each as
+    ``<name>_prefill`` and ``<name>_decode``: summed over layers by the
+    step programs, over decode steps on the device, and fetched once, at
+    gather.
 
     Four profiler annotations, ``launch.serve.prefill``,
     ``launch.serve.decode.dispatch``, ``launch.serve.decode.drain`` and
@@ -42,7 +47,8 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
         from repro.models import common as cc
         cc.RUNTIME["use_flash"] = True   # Pallas flash/decode kernels
     api = get_api(cfg)
-    prefill_fn = make_prefill(cfg, api)
+    counted = cfg.family not in ("audio", "vlm") and has_moe(cfg)
+    prefill_fn = make_prefill(cfg, api, counts=counted)
     # donate the cache pytree (argnum 3): decode_step's dynamic-update-slice
     # then updates the caches in place, reusing the allocation across steps
     decode_fn = jax.jit(make_decode_step(cfg, api), donate_argnums=(3,))
@@ -52,19 +58,21 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
 
     t0 = time.perf_counter()
     with TraceAnnotation("launch.serve.prefill"):
-        last_logits, caches = jax.jit(prefill_fn, static_argnums=(2,))(
-            params, batch, max_len)
+        last_logits, caches, *counts = jax.jit(
+            prefill_fn, static_argnums=(2,))(params, batch, max_len)
         token = jnp.argmax(last_logits[:, -1], axis=-1).astype(
             jnp.int32)[:, None]
         jax.block_until_ready(token)
     t_prefill = time.perf_counter() - t0
 
     out = [token]
+    totals = [jax.tree.map(jnp.zeros_like, c) for c in counts]
     t0 = time.perf_counter()
     with TraceAnnotation("launch.serve.decode.dispatch"):
         for i in range(gen_tokens - 1):
             pos = jnp.int32(extra + s + i)
-            token, caches = decode_fn(params, token, pos, caches)
+            token, caches, *totals = decode_fn(params, token, pos, caches,
+                                               *totals)
             out.append(token)
     t1 = time.perf_counter()
     with TraceAnnotation("launch.serve.decode.drain"):
@@ -74,6 +82,7 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
     t_decode = t_dispatch + t_drain
     with TraceAnnotation("launch.serve.gather"):
         gen = np.asarray(jnp.concatenate(out, axis=1))
+        counts = jax.device_get({"prefill": counts, "decode": totals})
     decode_steps = gen_tokens - 1
     stats = {
         "batch": b,
@@ -91,6 +100,8 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
         "decode_s_per_token": (t_decode / max(b * decode_steps, 1)),
         "backend": jax.default_backend(),
     }
+    stats.update({f"{k}_{phase}": int(v) for phase, c in counts.items()
+                  for k, v in (c[0] if c else {}).items()})
     log(f"prefill {s} toks x{b}: {t_prefill:.2f}s; "
         f"decode {decode_steps} steps: {t_decode:.2f}s "
         f"({stats['tokens_per_s']:.1f} tok/s)")

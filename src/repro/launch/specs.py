@@ -110,7 +110,7 @@ def _cache_leaf_spec(kind: str, name: str, shape: tuple, stacked: bool,
     if kind in ("attn",) and name in ("k", "v") and core_rank == 4:
         put(off + 0, _BATCH_AXES)
         put(off + 1, _SEQ_AXES)        # flash-decode style cache split
-    elif kind == "mla" and name in ("ckv", "k_rope") and core_rank == 3:
+    elif kind == "mla" and name == "latent" and core_rank == 3:
         put(off + 0, _BATCH_AXES)
         put(off + 1, _SEQ_AXES)
     elif kind == "mamba":

@@ -11,10 +11,12 @@ with NamedSharding like any other state.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import AttnSpec, MLASpec
 from repro.models import common as cc
@@ -250,7 +252,67 @@ def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
 
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V2): low-rank Q and compressed KV with decoupled RoPE.
+#
+# The cache holds one latent row per token, c_kv ‖ k_rope (kv_lora_rank +
+# qk_rope_dim). Prefill expands it per head and, under use_flash, runs the
+# flash kernel over q_nope ‖ q_rope against k_nope ‖ k_rope with v padded to
+# the same width. Absorbed decode folds wkv_b's key half into the query and
+# its value half into the output, so the decode kernel attends 128 query
+# heads against the single latent head: K = V = the latent row, and the
+# first kv_lora_rank output dims are sum_t w_t c_kv[t].
 # ---------------------------------------------------------------------------
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (1 without scaling)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_ramp(spec: MLASpec) -> tuple[int, int]:
+    """Rope pair indices where YaRN's ramp from extrapolated (below) to
+    interpolated (above) frequencies starts and ends."""
+    dim, base = spec.qk_rope_dim, spec.rope_theta
+
+    def corr(rotations):
+        return (dim * math.log(spec.yarn_original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = math.floor(corr(spec.yarn_beta_fast))
+    high = math.ceil(corr(spec.yarn_beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def mla_rope_freqs(spec: MLASpec):
+    """Inverse frequencies of the rope dims (qk_rope_dim // 2,), YaRN's
+    blend of theta's and theta's / factor; None without YaRN."""
+    if spec.yarn_factor <= 1:
+        return None
+    half = spec.qk_rope_dim // 2
+    extra = 1.0 / (spec.rope_theta
+                   ** (np.arange(half, dtype=np.float32) / half))
+    inter = extra / np.float32(spec.yarn_factor)
+    low, high = yarn_ramp(spec)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / ((high - low) or 0.001), 0, 1)
+    return jnp.asarray(inter * ramp + extra * (1 - ramp), jnp.float32)
+
+
+def mla_softmax_scale(spec: MLASpec) -> float:
+    scale = (spec.qk_nope_dim + spec.qk_rope_dim) ** -0.5
+    if spec.yarn_mscale_all_dim:
+        scale *= yarn_mscale(spec.yarn_factor, spec.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(spec: MLASpec, x, positions):
+    out = apply_rope(x, positions, spec.rope_theta, mla_rope_freqs(spec))
+    if spec.yarn_factor > 1 and spec.yarn_mscale_all_dim:
+        cs = (yarn_mscale(spec.yarn_factor, spec.yarn_mscale)
+              / yarn_mscale(spec.yarn_factor, spec.yarn_mscale_all_dim))
+        if cs != 1.0:
+            out = (out.astype(jnp.float32) * cs).astype(x.dtype)
+    return out
+
+
 def init_mla(key, spec: MLASpec, d_model: int, dtype) -> dict:
     ks = jax.random.split(key, 5)
     h = spec.n_heads
@@ -274,18 +336,17 @@ def _mla_q(p, spec: MLASpec, x, positions):
     q = apply_norm(p["q_norm"], x @ p["wq_a"], "rmsnorm") @ p["wq_b"]
     q = q.reshape(b, s, h, spec.qk_nope_dim + spec.qk_rope_dim)
     q_nope, q_rope = jnp.split(q, [spec.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, spec.rope_theta)
-    return q_nope, q_rope
+    return q_nope, _mla_rope(spec, q_rope, positions)
 
 
-def _mla_ckv(p, spec: MLASpec, x, positions):
-    """Returns (normalized compressed kv, rotated shared k_rope)."""
+def _mla_latent(p, spec: MLASpec, x, positions):
+    """The cache row of each token, (B, S, L + R): normalized compressed kv
+    ‖ rotated shared k_rope."""
     kv_a = x @ p["wkv_a"]
     c_kv, k_rope = jnp.split(kv_a, [spec.kv_lora_rank], axis=-1)
     c_kv = apply_norm(p["kv_norm"], c_kv, "rmsnorm")          # (B,S,L)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        spec.rope_theta)[:, :, 0, :]          # (B,S,R)
-    return c_kv, k_rope
+    k_rope = _mla_rope(spec, k_rope[:, :, None, :], positions)[:, :, 0, :]
+    return jnp.concatenate([c_kv, k_rope.astype(c_kv.dtype)], axis=-1)
 
 
 def _mla_chunked(q_nope, q_rope, k_nope, k_rope, v, scale, q_chunk: int,
@@ -316,96 +377,120 @@ def _mla_chunked(q_nope, q_rope, k_nope, k_rope, v, scale, q_chunk: int,
     return out.transpose(1, 0, 2, 3, 4).reshape(b, s, -1)
 
 
-def mla_full(p, spec: MLASpec, x, positions):
+def _mla_flash(q_nope, q_rope, k_nope, k_rope, v, scale):
+    """Causal MLA through the flash kernel: every head attends with
+    q_nope ‖ q_rope against k_nope ‖ k_rope (k_rope broadcast over heads);
+    q, k and v are zero-padded to one width, the output sliced back."""
+    from repro.kernels.flash_attention import ops as flash_ops
+    b, s, h, _ = q_nope.shape
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[:, :, None, :].astype(k_nope.dtype),
+        (b, s, h, k_rope.shape[-1]))], axis=-1)
+    dv = v.shape[-1]
+    d = max(q.shape[-1], dv)
+
+    def pad(a):
+        return jnp.pad(a, ((0, 0),) * 3 + ((0, d - a.shape[-1]),))
+
+    out = flash_ops.flash_attention(pad(q), pad(k), pad(v), scale=scale)
+    return out[..., :dv].reshape(b, s, -1)
+
+
+def mla_full(p, spec: MLASpec, x, positions, return_latent: bool = False):
+    """Training / prefill MLA; with ``return_latent`` also the cache rows
+    (B, S, L + R)."""
     b, s, _ = x.shape
     h = spec.n_heads
     q_nope, q_rope = _mla_q(p, spec, x, positions)
-    c_kv, k_rope = _mla_ckv(p, spec, x, positions)
+    latent = _mla_latent(p, spec, x, positions)
+    c_kv, k_rope = jnp.split(latent, [spec.kv_lora_rank], axis=-1)
     kv = (c_kv @ p["wkv_b"]).reshape(b, s, h, spec.qk_nope_dim + spec.v_head_dim)
     k_nope, v = jnp.split(kv, [spec.qk_nope_dim], axis=-1)
     k_nope = logical_constraint(k_nope, cc.BATCH, None, cc.HEADS, None)
     v = logical_constraint(v, cc.BATCH, None, cc.HEADS, None)
-    scale = (spec.qk_nope_dim + spec.qk_rope_dim) ** -0.5
+    scale = mla_softmax_scale(spec)
     q_chunk = FLAGS["q_chunk"]
-    if q_chunk and s % q_chunk == 0 and s > q_chunk:
+    if FLAGS["use_flash"]:
+        out = _mla_flash(q_nope, q_rope, k_nope, k_rope, v, scale)
+    elif q_chunk and s % q_chunk == 0 and s > q_chunk:
         out = _mla_chunked(q_nope, q_rope, k_nope, k_rope, v, scale, q_chunk,
                            x.dtype)
-        return out @ p["wo"]
-    scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
-              + jnp.einsum("bshr,btr->bhst", q_rope, k_rope)).astype(jnp.float32)
-    scores *= scale
-    mask = causal_mask(positions, positions)
-    scores = jnp.where(mask[:, None] if mask.ndim == 3 else mask[None, None],
-                       scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", w, v).reshape(b, s, -1)
-    return out @ p["wo"]
+    else:
+        scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
+                  + jnp.einsum("bshr,btr->bhst", q_rope, k_rope)
+                  ).astype(jnp.float32)
+        scores *= scale
+        mask = causal_mask(positions, positions)
+        scores = jnp.where(mask[:, None] if mask.ndim == 3
+                           else mask[None, None], scores, -1e30)
+        w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bhst,bthd->bshd", w, v).reshape(b, s, -1)
+    y = out @ p["wo"]
+    return (y, latent) if return_latent else y
 
 
 def init_mla_cache(spec: MLASpec, batch: int, max_len: int, dtype) -> dict:
     """The MLA win: cache only (kv_lora_rank + rope_dim) per token."""
-    return {
-        "ckv": jnp.zeros((batch, max_len, spec.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, max_len, spec.qk_rope_dim), dtype),
-    }
+    return {"latent": jnp.zeros(
+        (batch, max_len, spec.kv_lora_rank + spec.qk_rope_dim), dtype)}
 
 
 def mla_prefill(p, spec: MLASpec, x, positions, max_len: int):
     b, s, _ = x.shape
-    y = mla_full(p, spec, x, positions)
-    c_kv, k_rope = _mla_ckv(p, spec, x, positions)
+    y, latent = mla_full(p, spec, x, positions, return_latent=True)
     cache = init_mla_cache(spec, b, max_len, x.dtype)
-    cache["ckv"] = jax.lax.dynamic_update_slice(
-        cache["ckv"], c_kv.astype(x.dtype), (0, 0, 0))
-    cache["k_rope"] = jax.lax.dynamic_update_slice(
-        cache["k_rope"], k_rope.astype(x.dtype), (0, 0, 0))
+    cache["latent"] = jax.lax.dynamic_update_slice(
+        cache["latent"], latent.astype(x.dtype), (0, 0, 0))
     return y, cache
 
 
-def mla_decode(p, spec: MLASpec, x, pos, cache: dict, absorb: bool = False,
-               layer=None):
-    """One-token MLA decode. absorb=True uses the matmul-absorbed order
-    (never re-expands K/V for the whole cache — the §Perf variant).
-    ``layer`` is as in ``attn_decode``: with it, the new compressed row is
-    written in place into the layer-stacked cache."""
+def _decode_block(t: int) -> int:
+    """A decode kernel block that divides ``t`` slots, so the latent slab
+    is not padded."""
+    return next((bk for bk in (512, 256, 128) if t % bk == 0), 512)
+
+
+def mla_decode(p, spec: MLASpec, x, pos, cache: dict, layer=None):
+    """One-token MLA decode in the matmul-absorbed order: wkv_b's key half
+    is folded into the query and its value half into the output, so K/V are
+    never re-expanded for the whole cache; the attention runs through the
+    decode kernel under use_flash. ``layer`` is as in ``attn_decode``: with
+    it, the new latent row is written in place into the layer-stacked
+    cache."""
     b = x.shape[0]
-    h = spec.n_heads
+    h, lr = spec.n_heads, spec.kv_lora_rank
     positions = jnp.full((b, 1), pos, jnp.int32)
     q_nope, q_rope = _mla_q(p, spec, x, positions)            # (B,1,H,*)
-    c_new, r_new = _mla_ckv(p, spec, x, positions)            # (B,1,L),(B,1,R)
-    new_cache = {
-        "ckv": cache_put(cache["ckv"], c_new, (0, pos, 0), layer),
-        "k_rope": cache_put(cache["k_rope"], r_new, (0, pos, 0), layer),
-    }
-    ckv = cache_slab(new_cache["ckv"], layer)
-    k_rope = cache_slab(new_cache["k_rope"], layer)
+    row = _mla_latent(p, spec, x, positions)                  # (B,1,L+R)
+    new_cache = {"latent": cache_put(cache["latent"], row, (0, pos, 0),
+                                     layer)}
+    latent = cache_slab(new_cache["latent"], layer)           # (B,T,L+R)
 
-    t = ckv.shape[1]
+    t = latent.shape[1]
     valid = jnp.arange(t, dtype=jnp.int32) <= pos
-    scale = (spec.qk_nope_dim + spec.qk_rope_dim) ** -0.5
-    wkv_b = p["wkv_b"].reshape(spec.kv_lora_rank, h,
-                               spec.qk_nope_dim + spec.v_head_dim)
+    scale = mla_softmax_scale(spec)
+    wkv_b = p["wkv_b"].reshape(lr, h, spec.qk_nope_dim + spec.v_head_dim)
     w_k = wkv_b[..., :spec.qk_nope_dim]    # (L,H,N)
     w_v = wkv_b[..., spec.qk_nope_dim:]    # (L,H,V)
 
-    if absorb:
+    with jax.named_scope("mla_absorb"):
         q_eff = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_k)     # (B,1,H,L)
-        scores = (jnp.einsum("bqhl,btl->bhqt", q_eff, ckv)
-                  + jnp.einsum("bqhr,btr->bhqt", q_rope, k_rope))
+        q_cat = jnp.concatenate([q_eff, q_rope.astype(q_eff.dtype)],
+                                axis=-1)                       # (B,1,H,L+R)
+    if FLAGS["use_flash"]:
+        from repro.kernels.decode_attention import ops as dec_ops
+        kv = latent[:, :, None, :]                             # one kv head
+        ctx = dec_ops.decode_attention(q_cat, kv, kv, valid, scale=scale,
+                                       block_kv=_decode_block(t))
+        ctx = ctx[..., :lr]
     else:
-        kv = (ckv @ p["wkv_b"]).reshape(b, t, h,
-                                        spec.qk_nope_dim + spec.v_head_dim)
-        k_nope, v_full = jnp.split(kv, [spec.qk_nope_dim], axis=-1)
-        scores = (jnp.einsum("bqhn,bthn->bhqt", q_nope, k_nope)
-                  + jnp.einsum("bqhr,btr->bhqt", q_rope, k_rope))
-    scores = scores.astype(jnp.float32) * scale
-    scores = jnp.where(valid[None, None, None, :], scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-
-    if absorb:
-        ctx = jnp.einsum("bhqt,btl->bqhl", w, ckv)            # (B,1,H,L)
-        out = jnp.einsum("bqhl,lhv->bqhv", ctx, w_v)
-    else:
-        out = jnp.einsum("bhqt,bthv->bqhv", w, v_full)
+        scores = jnp.einsum("bqhc,btc->bhqt", q_cat, latent)
+        scores = scores.astype(jnp.float32) * scale
+        scores = jnp.where(valid[None, None, None, :], scores, -1e30)
+        w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        ctx = jnp.einsum("bhqt,btl->bqhl", w, latent[..., :lr])
+    with jax.named_scope("mla_absorb"):
+        out = jnp.einsum("bqhl,lhv->bqhv", ctx.astype(x.dtype), w_v)
     y = out.reshape(b, 1, -1) @ p["wo"]
     return y, new_cache

@@ -97,10 +97,13 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               freqs=None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+    ``freqs`` (head_dim // 2,) replaces theta's inverse frequencies."""
     half = x.shape[-1] // 2
-    freqs = rope_freqs(x.shape[-1], theta)                    # (half,)
+    if freqs is None:
+        freqs = rope_freqs(x.shape[-1], theta)                # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, half)
     cos = jnp.cos(angles)[..., None, :]                        # (..., seq, 1, half)
     sin = jnp.sin(angles)[..., None, :]

@@ -19,6 +19,11 @@ its new row in place into the stacked arrays and reads its slab from them,
 and a recurrent layer writes its new state slab back, so no layer's cache is
 sliced out and restacked whole. The carry keeps the layout the caches enter
 the step in, so the donated cache is updated where it lies.
+
+A served MoE layer (``mlp.moe_dropless``) also returns counts of its work,
+int32 scalars; the layer and block functions return them beside the caches
+(empty for every other layer) and ``prefill`` / ``decode_step`` sum them
+over the layers when asked (``counts=True``).
 """
 from __future__ import annotations
 
@@ -102,9 +107,25 @@ def layer_cache_init(layer: LayerSpec, cfg: ModelConfig, batch: int,
     raise ValueError(layer.kind)
 
 
+def has_moe(cfg: ModelConfig) -> bool:
+    """Whether any layer of ``cfg`` is an MoE layer."""
+    return any(l.mlp == "moe" for seg in cfg.segments for l in seg.layers)
+
+
+def _add_counts(a: dict, b: dict) -> dict:
+    """Two layers' counts summed key by key."""
+    return {k: a.get(k, 0) + b.get(k, 0) for k in sorted(a.keys() | b.keys())}
+
+
+def _sum_layers(counts: dict) -> dict:
+    """Counts of a scanned segment, stacked over its repetitions, summed."""
+    return {k: jnp.sum(v, axis=0) for k, v in counts.items()}
+
+
 def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
                want_cache: bool, max_len: int):
-    """Full-sequence layer. Returns (x, aux, cache_or_None)."""
+    """Full-sequence layer. Returns (x, aux, cache_or_None, counts);
+    ``counts`` is a served MoE layer's (with ``want_cache``), else empty."""
     with jax.named_scope("norm"):
         h = apply_norm(p["norm1"], x, cfg.norm)
         # Pin the sequence-parallel boundary to the (low-precision) norm
@@ -112,7 +133,7 @@ def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
         # norm's f32 upcast and the boundary collective moves 2x the bytes
         # (SSPerf H1).
         h = logical_constraint(h, cc.BATCH, cc.SEQ, cc.EMBED)
-    cache = None
+    cache, counts = None, {}
     with jax.named_scope("attn"):
         if layer.kind == "attn":
             if want_cache:
@@ -150,22 +171,27 @@ def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
         with jax.named_scope("mlp"):
             if layer.mlp == "dense":
                 y2 = mlp_mod.mlp(p["mlp"], h2, cfg.act)
+            elif want_cache:          # serving: dropless, this layer's share
+                y2, counts = mlp_mod.moe_dropless(p["moe"], layer.moe, h2,
+                                                  cfg.act)
             else:
                 y2, aux = mlp_mod.moe(p["moe"], layer.moe, h2, cfg.act,
                                       seq_chunk=cfg.moe_seq_chunk)
             x = x + checkpoint_name(y2, "block_out")
     x = logical_constraint(x, cc.BATCH, cc.SEQ, cc.EMBED)
-    return x, aux, cache
+    return x, aux, cache, counts
 
 
 def layer_decode(p, layer: LayerSpec, cfg: ModelConfig, x, pos, cache,
                  index=None):
-    """Single-token layer step. Returns (x, new_cache).
+    """Single-token layer step. Returns (x, new_cache, counts).
 
     With ``index`` (int32 scalar), ``cache`` is the segment's layer-stacked
     cache and this layer is its slab ``index``; the returned cache stays
     stacked. Attention layers append their row in place; recurrent layers
-    replace their whole state slab."""
+    replace their whole state slab. An MoE layer is dropless and returns
+    its counts; every other layer returns empty ``counts``."""
+    counts = {}
     with jax.named_scope("norm"):
         h = apply_norm(p["norm1"], x, cfg.norm)
     with jax.named_scope("attn"):
@@ -174,7 +200,7 @@ def layer_decode(p, layer: LayerSpec, cfg: ModelConfig, x, pos, cache,
                                             cache, index)
         elif layer.kind == "mla":
             y, cache = attn_mod.mla_decode(p["mla"], layer.mla, h, pos, cache,
-                                           absorb=cfg.mla_absorb, layer=index)
+                                           layer=index)
         else:
             state = jax.tree.map(lambda a: attn_mod.cache_slab(a, index),
                                  cache)
@@ -198,33 +224,35 @@ def layer_decode(p, layer: LayerSpec, cfg: ModelConfig, x, pos, cache,
             if layer.mlp == "dense":
                 y2 = mlp_mod.mlp(p["mlp"], h2, cfg.act)
             else:
-                y2, _ = mlp_mod.moe(p["moe"], layer.moe, h2, cfg.act,
-                                    decode=True)
+                y2, counts = mlp_mod.moe_dropless(p["moe"], layer.moe, h2,
+                                                  cfg.act)
             x = x + y2
-    return x, cache
+    return x, cache, counts
 
 
 def block_full(block_p, seg: Segment, cfg: ModelConfig, x, positions,
                want_cache: bool, max_len: int):
     """One block (all layers of a segment repetition). Returns
-    (x, aux_sum, [caches])."""
+    (x, aux_sum, [caches], counts)."""
     aux_sum = jnp.zeros((), jnp.float32)
-    caches = []
+    caches, counts = [], {}
     for p_i, layer in zip(block_p, seg.layers):
-        x, aux, cache = layer_full(p_i, layer, cfg, x, positions, want_cache,
-                                   max_len)
+        x, aux, cache, c = layer_full(p_i, layer, cfg, x, positions,
+                                      want_cache, max_len)
         aux_sum = aux_sum + aux
         caches.append(cache)
-    return x, aux_sum, caches
+        counts = _add_counts(counts, c)
+    return x, aux_sum, caches, counts
 
 
 def block_decode(block_p, block_c, seg: Segment, cfg: ModelConfig, x, pos,
                  index=None):
-    new_caches = []
+    new_caches, counts = [], {}
     for p_i, c_i, layer in zip(block_p, block_c, seg.layers):
-        x, c = layer_decode(p_i, layer, cfg, x, pos, c_i, index)
+        x, c, n = layer_decode(p_i, layer, cfg, x, pos, c_i, index)
         new_caches.append(c)
-    return x, new_caches
+        counts = _add_counts(counts, n)
+    return x, new_caches, counts
 
 
 # ---------------------------------------------------------------------------
@@ -283,29 +311,32 @@ def _maybe_remat(fn, cfg: ModelConfig):
 
 def backbone_full(params, cfg: ModelConfig, x, positions,
                   want_cache: bool, max_len: int):
-    """Run all segments over embeddings x. Returns (x, aux, caches)."""
+    """Run all segments over embeddings x. Returns (x, aux, caches,
+    counts)."""
     aux_total = jnp.zeros((), jnp.float32)
-    caches = []
+    caches, counts = [], {}
     for seg, seg_p in zip(cfg.segments, params["segments"]):
         if seg.count == 1:
             fn = _maybe_remat(
                 lambda p, h, _s=seg: block_full(p, _s, cfg, h, positions,
                                                 want_cache, max_len), cfg)
-            x, aux, cache = fn(seg_p, x)
+            x, aux, cache, c = fn(seg_p, x)
             aux_total = aux_total + aux
             caches.append(cache)
         else:
             def body(carry, p_i, _seg=seg):
                 h, aux_acc = carry
-                h2, aux_i, cache_i = block_full(p_i, _seg, cfg, h, positions,
-                                                want_cache, max_len)
-                return (h2, aux_acc + aux_i), cache_i
+                h2, aux_i, cache_i, c_i = block_full(
+                    p_i, _seg, cfg, h, positions, want_cache, max_len)
+                return (h2, aux_acc + aux_i), (cache_i, c_i)
 
             body_fn = _maybe_remat(body, cfg)
-            (x, aux_total), seg_caches = jax.lax.scan(
+            (x, aux_total), (seg_caches, c) = jax.lax.scan(
                 body_fn, (x, aux_total), seg_p)
             caches.append(seg_caches)
-    return x, aux_total, caches
+            c = _sum_layers(c)
+        counts = _add_counts(counts, c)
+    return x, aux_total, caches, counts
 
 
 def _logits(params, cfg: ModelConfig, x):
@@ -319,6 +350,13 @@ def _logits(params, cfg: ModelConfig, x):
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
             want_cache: bool = False, max_len: int = 0):
     """tokens: (B,S) int32 (or embeds (B,S,d)). Returns (logits, aux, caches)."""
+    return _forward(params, cfg, tokens, embeds, want_cache, max_len)[:3]
+
+
+def _forward(params, cfg: ModelConfig, tokens, embeds, want_cache: bool,
+             max_len: int):
+    """``forward``, and the layers' counts summed: (logits, aux, caches,
+    counts)."""
     if embeds is None:
         with jax.named_scope("embed"):
             embeds = params["embed"][tokens]
@@ -326,11 +364,11 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
     b, s = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     max_len = max_len or s
-    x, aux, caches = backbone_full(params, cfg, x, positions, want_cache,
-                                   max_len)
+    x, aux, caches, counts = backbone_full(params, cfg, x, positions,
+                                           want_cache, max_len)
     with jax.named_scope("lm_head"):
         x = apply_norm(params["final_norm"], x, cfg.norm)
-        return _logits(params, cfg, x), aux, caches
+        return _logits(params, cfg, x), aux, caches, counts
 
 
 def _chunked_ce(params, cfg: ModelConfig, x, labels):
@@ -369,7 +407,7 @@ def loss_and_metrics(params, cfg: ModelConfig, batch: dict):
         x = logical_constraint(embeds, cc.BATCH, cc.SEQ, cc.EMBED)
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
                                      (b, s))
-        x, aux, _ = backbone_full(params, cfg, x, positions, False, s)
+        x, aux, _, _ = backbone_full(params, cfg, x, positions, False, s)
         with jax.named_scope("lm_head"):
             x = apply_norm(params["final_norm"], x, cfg.norm)
             ce = _chunked_ce(params, cfg, x, labels)
@@ -382,11 +420,12 @@ def loss_and_metrics(params, cfg: ModelConfig, batch: dict):
 
 
 def prefill(params, cfg: ModelConfig, tokens=None, embeds=None,
-            max_len: int = 0):
-    """Returns (logits_last (B,1,V), caches)."""
-    logits, _, caches = forward(params, cfg, tokens=tokens, embeds=embeds,
-                                want_cache=True, max_len=max_len)
-    return logits[:, -1:], caches
+            max_len: int = 0, counts: bool = False):
+    """Returns (logits_last (B,1,V), caches), and with ``counts`` also the
+    MoE layers' counts summed over layers."""
+    logits, _, caches, n = _forward(params, cfg, tokens, embeds, True,
+                                    max_len)
+    return (logits[:, -1:], caches) + ((n,) if counts else ())
 
 
 def _default_layout(x):
@@ -403,29 +442,34 @@ def _default_layout(x):
         x, Layout(Layout.from_pjrt_layout(pjrt).major_to_minor))
 
 
-def decode_step(params, cfg: ModelConfig, token, pos, caches):
-    """token: (B,1) int32; pos: scalar int32. Returns (logits, new_caches)."""
+def decode_step(params, cfg: ModelConfig, token, pos, caches,
+                counts: bool = False):
+    """token: (B,1) int32; pos: scalar int32. Returns (logits, new_caches),
+    and with ``counts`` also the step's MoE counts summed over layers."""
     with jax.named_scope("embed"):
         x = params["embed"][token]
-    new_caches = []
+    new_caches, n = [], {}
     for seg, seg_p, seg_c in zip(cfg.segments, params["segments"], caches):
         if seg.count == 1:
-            x, c = block_decode(seg_p, seg_c, seg, cfg, x, pos)
+            x, c, n_seg = block_decode(seg_p, seg_c, seg, cfg, x, pos)
             new_caches.append(c)
         else:
             def body(carry, xs, _seg=seg):
                 h, c = carry
                 p_i, idx = xs
-                h, c = block_decode(p_i, c, _seg, cfg, h, pos, idx)
-                return (h, jax.tree.map(_default_layout, c)), None
+                h, c, n_i = block_decode(p_i, c, _seg, cfg, h, pos, idx)
+                return (h, jax.tree.map(_default_layout, c)), n_i
 
-            (x, seg_new), _ = jax.lax.scan(
+            (x, seg_new), n_seg = jax.lax.scan(
                 body, (x, seg_c),
                 (seg_p, jnp.arange(seg.count, dtype=jnp.int32)))
             new_caches.append(seg_new)
+            n_seg = _sum_layers(n_seg)
+        n = _add_counts(n, n_seg)
     with jax.named_scope("lm_head"):
         x = apply_norm(params["final_norm"], x, cfg.norm)
-        return _logits(params, cfg, x), new_caches
+        logits = _logits(params, cfg, x)
+    return (logits, new_caches) + ((n,) if counts else ())
 
 
 def param_count(params) -> int:

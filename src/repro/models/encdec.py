@@ -61,11 +61,12 @@ def encode(params, cfg: ModelConfig, frames):
     x = frames
     for seg, seg_p in zip(cfg.encoder_segments, params["enc_segments"]):
         if seg.count == 1:
-            x, _, _ = dlm.block_full(seg_p, seg, cfg, x, positions, False, s)
+            x, _, _, _ = dlm.block_full(seg_p, seg, cfg, x, positions, False,
+                                        s)
         else:
             def body(h, p_i, _seg=seg):
-                h2, _, _ = dlm.block_full(p_i, _seg, cfg, h, positions,
-                                          False, s)
+                h2, _, _, _ = dlm.block_full(p_i, _seg, cfg, h, positions,
+                                             False, s)
                 return h2, None
 
             x, _ = jax.lax.scan(dlm._maybe_remat(body, cfg), x, seg_p)
@@ -101,8 +102,8 @@ def _dec_block_full(block_p, block_x, block_kv, seg: Segment,
     """Self-attn layer + cross-attn per layer in the block."""
     caches = []
     for p_i, xp_i, kv_i, layer in zip(block_p, block_x, block_kv, seg.layers):
-        x, _, cache = dlm.layer_full(p_i, layer, cfg, x, positions,
-                                     want_cache, max_len)
+        x, _, cache, _ = dlm.layer_full(p_i, layer, cfg, x, positions,
+                                        want_cache, max_len)
         h = apply_norm(xp_i["norm"], x, cfg.norm)
         x = x + attn_mod.attn_cross(xp_i["attn"], layer.attn, h, kv_i)
         caches.append(cache)
@@ -168,7 +169,7 @@ def decode_step(params, cfg: ModelConfig, token, pos, caches):
             new_c = []
             for p_i, xp_i, kv_i, c_i, layer in zip(p_b, xp_b, kv_b, c_b,
                                                    _seg.layers):
-                h, c2 = dlm.layer_decode(p_i, layer, cfg, h, pos, c_i)
+                h, c2, _ = dlm.layer_decode(p_i, layer, cfg, h, pos, c_i)
                 hc = apply_norm(xp_i["norm"], h, cfg.norm)
                 h = h + attn_mod.attn_cross(xp_i["attn"], layer.attn, hc, kv_i)
                 new_c.append(c2)

@@ -67,8 +67,11 @@ def make_eval_step(cfg: ModelConfig, api: ModelApi | None = None) -> Callable:
 # ---------------------------------------------------------------------------
 # Serving
 # ---------------------------------------------------------------------------
-def make_prefill(cfg: ModelConfig, api: ModelApi | None = None) -> Callable:
-    """(params, batch, max_len) -> (last_logits, caches)."""
+def make_prefill(cfg: ModelConfig, api: ModelApi | None = None,
+                 counts: bool = False) -> Callable:
+    """(params, batch, max_len) -> (last_logits, caches), and with
+    ``counts`` (a decoder LM's) the MoE counts of the prompt as a third
+    output (``decoder_lm.prefill``)."""
     api = api or get_api(cfg)
 
     def prefill_step(params, batch, max_len: int):
@@ -78,6 +81,9 @@ def make_prefill(cfg: ModelConfig, api: ModelApi | None = None) -> Callable:
         if cfg.family == "vlm":
             return api.prefill(params, cfg, batch["patches"], batch["tokens"],
                                max_len=max_len)
+        if counts:
+            return api.prefill(params, cfg, tokens=batch["tokens"],
+                               max_len=max_len, counts=True)
         return api.prefill(params, cfg, tokens=batch["tokens"],
                            max_len=max_len)
 
@@ -89,14 +95,23 @@ def make_decode_step(cfg: ModelConfig, api: ModelApi | None = None,
     """(params, token (B,1), pos scalar, caches) -> (next_token, new_caches).
 
     This is the `serve_step` the decode_* / long_* shapes lower: one new
-    token against a KV cache of the shape's seq_len."""
+    token against a KV cache of the shape's seq_len. Given a decoder LM's
+    running MoE counts as a fifth argument (a dict, as ``make_prefill``'s
+    ``counts`` returns them), it adds this step's and returns them as a
+    third output."""
     api = api or get_api(cfg)
 
-    def serve_step(params, token, pos, caches):
-        logits, new_caches = api.decode_step(params, cfg, token, pos, caches)
+    def serve_step(params, token, pos, caches, *counts):
+        if counts:
+            logits, new_caches, step = api.decode_step(
+                params, cfg, token, pos, caches, counts=True)
+            counts = (jax.tree.map(jnp.add, counts[0], step),)
+        else:
+            logits, new_caches = api.decode_step(params, cfg, token, pos,
+                                                 caches)
         with jax.named_scope("sample"):
             next_token = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return next_token[:, None], new_caches
+        return (next_token[:, None], new_caches) + counts
 
     return serve_step
 

@@ -152,3 +152,61 @@ def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
               and (" copy(" in line or " copy-start(" in line)]
     assert not copies, copies[:2]
     assert compiled.memory_analysis().temp_size_in_bytes < k.size * 2
+
+
+def _deepseek_share(count: int, moe: bool):
+    """DeepSeek-V2 at published widths, ``count`` stacked MLA layers, the
+    MoE ones holding experts 0..19 of the router's 160."""
+    cfg = get_config("deepseek-v2-236b")
+    layer = cfg.segments[1 if moe else 0].layers[0]
+    if moe:
+        layer = dataclasses.replace(layer, moe=dataclasses.replace(
+            layer.moe, first_local=0, n_local=20))
+    return dataclasses.replace(cfg, segments=(Segment(count=count,
+                                                      layers=(layer,)),))
+
+
+def test_absorbed_mla_decode_step_compiles(one_chip, monkeypatch):
+    """The absorbed MLA decode step at published widths (128 heads over one
+    latent head of 576), two stacked layers, batch 32, 768 slots, through
+    the compiled decode kernel, writing each latent row in place."""
+    from repro.models import decoder_lm as dlm
+    from repro.training.train_step import make_decode_step
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: list(one_chip.device_set))
+    monkeypatch.setitem(cc.RUNTIME, "use_flash", True)
+    cfg = _deepseek_share(2, moe=False)
+    place = lambda t: jax.tree.map(                               # noqa: E731
+        lambda s: _spec(s.shape, s.dtype, one_chip), t)
+    params = place(sp.param_struct(cfg))
+    caches = place(jax.eval_shape(lambda: dlm.init_caches(cfg, 32, 768)))
+    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(3,)).lower(
+        params, _spec((32, 1), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip), caches).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    latent = caches[0][0]["latent"]
+    assert latent.shape == (2, 32, 768, 576)
+    stacked = "bf16[" + ",".join(map(str, latent.shape)) + "]"
+    copies = [line for line in text.splitlines()
+              if stacked in line.split(" copy", 1)[0]
+              and (" copy(" in line or " copy-start(" in line)]
+    assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("tokens", [32, 16384], ids=["decode", "prefill"])
+def test_dropless_moe_layer_compiles(one_chip, tokens):
+    """The dropless MoE layer at published widths, 20 of 160 experts held,
+    for a decode step (batch 32) and a prefill (32 x 512): the expert
+    matmuls lower to the TPU's grouped matmul (``ragged-dot``)."""
+    from repro.models import mlp as mlp_mod
+    spec = _deepseek_share(1, moe=True).segments[0].layers[0].moe
+    params = jax.eval_shape(lambda k: mlp_mod.init_moe(
+        k, spec, 5120, "silu", jnp.bfloat16), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                          params)
+    assert params["w_up"].shape == (20, 5120, 1536)
+    x = _spec((32, tokens // 32, 5120), jnp.bfloat16, one_chip)
+    text = _compiled_text(
+        lambda p, x: mlp_mod.moe_dropless(p, spec, x, "silu"), params, x)
+    assert "ragged-dot" in text
