@@ -1,4 +1,4 @@
-"""jit wrapper: model layout -> kernel layout, padding, backend selection."""
+"""jit wrapper: model layout -> kernel layout, backend selection."""
 from __future__ import annotations
 
 import functools
@@ -10,29 +10,23 @@ from repro.kernels.decode_attention import kernel as _k
 from repro.kernels.decode_attention import ref as _ref
 
 
-@functools.partial(jax.jit, static_argnames=("block_kv", "force_ref",
-                                             "scale"))
-def decode_attention(q, k, v, valid, *, block_kv: int = _k.DEFAULT_BLOCK_KV,
-                     force_ref: bool = False, scale: float | None = None):
-    """Model layout: q (B, 1, H, D); k/v (B, T, KV, D); valid (T,) bool/int.
-    Returns (B, 1, H, D). ``scale`` multiplies the scores (default
-    D^-0.5)."""
+@functools.partial(jax.jit, static_argnames=("force_ref", "scale"))
+def decode_attention(q, k, v, valid, layer=None, *, force_ref: bool = False,
+                     scale: float | None = None):
+    """Model layout: q (B, 1, H, D); k/v (B, KV, D, T), slots minor, or
+    layer-stacked (L, B, KV, D, T) and read in place at ``layer`` (int32
+    scalar); valid (T,) bool/int. Returns (B, 1, H, D). ``scale``
+    multiplies the scores (default D^-0.5)."""
     if force_ref:
-        return _ref.decode_attention_ref(q, k, v, valid, scale=scale)
+        return _ref.decode_attention_ref(q, k, v, valid, layer, scale=scale)
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
     b, _, h, d = q.shape
-    t, kvh = k.shape[1], k.shape[2]
-    g = h // kvh
-    bk = min(block_kv, max(8, 1 << (t - 1).bit_length()))
-    pad = (-t) % bk
-    kt = k.transpose(0, 2, 1, 3)                      # (B, KV, T, D)
-    vt = v.transpose(0, 2, 1, 3)
+    kvh = k.shape[2]
+    qg = q.reshape(b, kvh, h // kvh, d)
     vmask = (valid > 0).astype(jnp.int32)[None, :]    # (1, T)
-    if pad:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        vmask = jnp.pad(vmask, ((0, 0), (0, pad)))    # padded slots invalid
-    qg = q.reshape(b, kvh, g, d)
     interpret = jax.default_backend() != "tpu"
-    o = _k.decode_attention_grouped(qg, kt, vt, vmask, block_kv=bk,
-                                    scale=scale, interpret=interpret)
+    o = _k.decode_attention_grouped(
+        qg, k, v, vmask, jnp.reshape(layer, (1,)).astype(jnp.int32),
+        scale=scale, interpret=interpret)
     return o.reshape(b, 1, h, d)

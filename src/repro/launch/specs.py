@@ -6,10 +6,10 @@ what the runtime would execute.
 
 Cache sharding policy (decode shapes):
   * batch dim        -> (pod, data)   [dropped when indivisible, e.g. B=1]
-  * KV-cache seq dim -> (model, data) minus already-used axes — sharding the
-    cache T dim turns the decode softmax/dot into partial+all-reduce
-    (a flash-decode schedule via GSPMD); with B=1 (long_500k) the cache
-    spreads over the whole pod.
+  * KV-cache seq dim, the last (caches hold their slots minor) -> (model,
+    data) minus already-used axes — sharding the cache T dim turns the
+    decode softmax/dot into partial+all-reduce (a flash-decode schedule via
+    GSPMD); with B=1 (long_500k) the cache spreads over the whole pod.
   * mamba/xlstm state feature dims -> model.
 """
 from __future__ import annotations
@@ -107,12 +107,10 @@ def _cache_leaf_spec(kind: str, name: str, shape: tuple, stacked: bool,
     core_rank = len(shape) - off
     if name == "slot_pos":
         return P(*spec)
-    if kind in ("attn",) and name in ("k", "v") and core_rank == 4:
+    if kind in ("attn", "mla") and name in ("k", "v", "latent") \
+            and core_rank == 4:        # (B, KV, D, T): slots minor
         put(off + 0, _BATCH_AXES)
-        put(off + 1, _SEQ_AXES)        # flash-decode style cache split
-    elif kind == "mla" and name == "latent" and core_rank == 3:
-        put(off + 0, _BATCH_AXES)
-        put(off + 1, _SEQ_AXES)
+        put(off + 3, _SEQ_AXES)        # flash-decode style cache split
     elif kind == "mamba":
         put(off + 0, _BATCH_AXES)
         if name == "h" and core_rank == 3:

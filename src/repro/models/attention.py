@@ -4,7 +4,8 @@ Two execution paths per layer:
   * full-sequence (train / prefill) — optionally routed through the Pallas
     flash-attention kernel (FLAGS["use_flash"], TPU target);
   * single-token decode against a KV cache — full cache, ring (sliding-window)
-    cache, or MLA compressed cache (plain or absorbed matmul order).
+    cache, or MLA compressed cache (absorbed matmul order). Decode caches
+    hold their slots minor, the layout the decode kernel reads in place.
 
 Shapes: x (B, S, d_model); caches live in a dict pytree so they pjit-shard
 with NamedSharding like any other state.
@@ -59,18 +60,20 @@ def _project_qkv(p, spec: AttnSpec, x, positions):
     return q, k, v
 
 
-def _gqa_attend(q, k, v, mask):
-    """q: (B,S,H,D) k/v: (B,T,KV,D); grouped einsum, no KV repetition."""
+def _gqa_attend(q, k, v, mask, kv: str = "btkd"):
+    """q: (B,S,H,D) k/v: (B,T,KV,D), or in the axis order ``kv`` names
+    (``"bkdt"``: a decode cache, slots minor); grouped einsum, no KV
+    repetition."""
     b, s, h, dh = q.shape
-    kvh = k.shape[2]
+    kvh = k.shape[kv.index("k")]
     g = h // kvh
     qg = q.reshape(b, s, kvh, g, dh)
-    scores = jnp.einsum("bskgd,btkd->bksgt", qg, k).astype(jnp.float32)
+    scores = jnp.einsum(f"bskgd,{kv}->bksgt", qg, k).astype(jnp.float32)
     scores *= dh ** -0.5
     scores = jnp.where(mask[:, None, :, None, :] if mask.ndim == 3
                        else mask[None, None, :, None, :], scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bksgt,btkd->bskgd", w, v)
+    out = jnp.einsum(f"bksgt,{kv}->bskgd", w, v)
     return out.reshape(b, s, h, dh)
 
 
@@ -150,17 +153,25 @@ def attn_cross(p, spec: AttnSpec, x, kv_cache: tuple):
 
 
 # -- KV caches ---------------------------------------------------------------
-def cache_len(spec: AttnSpec, max_len: int) -> int:
-    return max_len if spec.window is None else min(spec.window, max_len)
+# Decode caches hold their slots minor, (B, KV, D, T), stacked (L, B, KV, D, T)
+# in a scanned segment: the layout the decode kernel's blocks read, so it
+# reads a layer's K/V where they lie. Slot counts above 128 are rounded up to
+# a multiple of 128 (the kernel's blocks divide them); the extra slots are
+# never valid.
+def cache_slots(n: int) -> int:
+    """Slots of a decode cache for ``n`` positions."""
+    return n if n <= 128 else -(-n // 128) * 128
 
 
 def init_cache(spec: AttnSpec, batch: int, max_len: int, dtype) -> dict:
-    """Full cache, or ring cache bounded at the sliding window."""
-    t = max_len if spec.window is None else min(spec.window, max_len)
+    """Full cache, or ring cache bounded at the sliding window; k/v
+    (B, KV, D, T), slots minor."""
+    t = cache_slots(max_len if spec.window is None
+                    else min(spec.window, max_len))
     kv, dh = spec.n_kv_heads, spec.head_dim
     cache = {
-        "k": jnp.zeros((batch, t, kv, dh), dtype),
-        "v": jnp.zeros((batch, t, kv, dh), dtype),
+        "k": jnp.zeros((batch, kv, dh, t), dtype),
+        "v": jnp.zeros((batch, kv, dh, t), dtype),
     }
     if spec.window is not None:
         # per-slot absolute positions (-1 = empty)
@@ -173,23 +184,20 @@ def attn_prefill(p, spec: AttnSpec, x, positions, max_len: int):
     0..S-1 (no padding). Returns (y, cache)."""
     b, s, _ = x.shape
     y, (k, v) = attn_full(p, spec, x, positions, return_kv=True)
-    t = cache_len(spec, max_len)
+    k, v = k.transpose(0, 2, 3, 1), v.transpose(0, 2, 3, 1)   # (B,KV,D,S)
+    cache = init_cache(spec, b, max_len, x.dtype)
     if spec.window is None:
-        cache = init_cache(spec, b, max_len, x.dtype)
         cache["k"] = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0))
         cache["v"] = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0))
     else:
-        # last min(S, W) tokens land in their ring slots
-        w = t
+        # last min(S, T) tokens land in their ring slots
+        w = cache["k"].shape[-1]
         take = min(s, w)
         idx = jnp.arange(s - take, s, dtype=jnp.int32)       # absolute positions
         slots = jnp.mod(idx, w)
-        kk = jnp.zeros((b, w) + k.shape[2:], x.dtype).at[:, slots].set(
-            k[:, s - take:])
-        vv = jnp.zeros((b, w) + v.shape[2:], x.dtype).at[:, slots].set(
-            v[:, s - take:])
-        slot_pos = jnp.full((w,), -1, jnp.int32).at[slots].set(idx)
-        cache = {"k": kk, "v": vv, "slot_pos": slot_pos}
+        cache["k"] = cache["k"].at[..., slots].set(k[..., s - take:])
+        cache["v"] = cache["v"].at[..., slots].set(v[..., s - take:])
+        cache["slot_pos"] = cache["slot_pos"].at[slots].set(idx)
     return y, cache
 
 
@@ -216,19 +224,21 @@ def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
 
     ``layer`` (int32 scalar) says ``cache`` is a segment's layer-stacked
     cache, each leaf with a leading (count,) axis, and this is its layer
-    ``layer``: the new K/V row (and ring slot position) is written in place
-    into the stacked arrays, the layer's slab is read from them as the
-    attention's operand, and the returned cache keeps the stacked shape.
-    ``None`` is one layer's unstacked cache."""
+    ``layer``: the new K/V slot column (and ring slot position) is written
+    in place into the stacked arrays, the decode kernel reads the layer's
+    K/V from them by index, and the returned cache keeps the stacked shape.
+    ``None`` is one layer's unstacked cache. k/v are (B, KV, D, T)."""
     b = x.shape[0]
     positions = jnp.full((b, 1), pos, jnp.int32)
     q, k_new, v_new = _project_qkv(p, spec, x, positions)
 
-    t = cache["k"].shape[-3]
+    t = cache["k"].shape[-1]
     slot = pos if spec.window is None else jnp.mod(pos, t)
-    new_cache = {
-        "k": cache_put(cache["k"], k_new, (0, slot, 0, 0), layer),
-        "v": cache_put(cache["v"], v_new, (0, slot, 0, 0), layer),
+    new_cache = {   # the new row as one slot column (B, KV, D, 1)
+        "k": cache_put(cache["k"], k_new.transpose(0, 2, 3, 1),
+                       (0, 0, 0, slot), layer),
+        "v": cache_put(cache["v"], v_new.transpose(0, 2, 3, 1),
+                       (0, 0, 0, slot), layer),
     }
     if spec.window is None:
         valid = jnp.arange(t, dtype=jnp.int32) <= pos
@@ -237,15 +247,15 @@ def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
             cache["slot_pos"], jnp.full((1,), pos, jnp.int32), (slot,), layer)
         slot_pos = cache_slab(new_cache["slot_pos"], layer)
         valid = (slot_pos >= 0) & (slot_pos <= pos) & (slot_pos > pos - spec.window)
-    k = cache_slab(new_cache["k"], layer)
-    v = cache_slab(new_cache["v"], layer)
 
     if FLAGS["use_flash"]:
         from repro.kernels.decode_attention import ops as dec_ops
-        out = dec_ops.decode_attention(q, k, v, valid)
+        out = dec_ops.decode_attention(q, new_cache["k"], new_cache["v"],
+                                       valid, layer)
     else:
         mask = valid[None, None, :]  # (1,1,T) broadcast over batch, q=1
-        out = _gqa_attend(q, k, v, mask)
+        out = _gqa_attend(q, cache_slab(new_cache["k"], layer),
+                          cache_slab(new_cache["v"], layer), mask, kv="bkdt")
     y = out.reshape(b, 1, -1) @ p["wo"]
     return y, new_cache
 
@@ -254,9 +264,10 @@ def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
 # MLA (DeepSeek-V2): low-rank Q and compressed KV with decoupled RoPE.
 #
 # The cache holds one latent row per token, c_kv ‖ k_rope (kv_lora_rank +
-# qk_rope_dim). Prefill expands it per head and, under use_flash, runs the
-# flash kernel over q_nope ‖ q_rope against k_nope ‖ k_rope with v padded to
-# the same width. Absorbed decode folds wkv_b's key half into the query and
+# qk_rope_dim), as one kv head with its slots minor, (B, 1, L + R, T).
+# Prefill expands it per head and, under use_flash, runs the flash kernel
+# over q_nope ‖ q_rope against k_nope ‖ k_rope with v padded to the same
+# width. Absorbed decode folds wkv_b's key half into the query and
 # its value half into the output, so the decode kernel attends 128 query
 # heads against the single latent head: K = V = the latent row, and the
 # first kv_lora_rank output dims are sum_t w_t c_kv[t].
@@ -431,9 +442,11 @@ def mla_full(p, spec: MLASpec, x, positions, return_latent: bool = False):
 
 
 def init_mla_cache(spec: MLASpec, batch: int, max_len: int, dtype) -> dict:
-    """The MLA win: cache only (kv_lora_rank + rope_dim) per token."""
+    """The MLA win: cache only (kv_lora_rank + rope_dim) per token, held as
+    one kv head with its slots minor, (B, 1, L + R, T)."""
     return {"latent": jnp.zeros(
-        (batch, max_len, spec.kv_lora_rank + spec.qk_rope_dim), dtype)}
+        (batch, 1, spec.kv_lora_rank + spec.qk_rope_dim,
+         cache_slots(max_len)), dtype)}
 
 
 def mla_prefill(p, spec: MLASpec, x, positions, max_len: int):
@@ -441,33 +454,28 @@ def mla_prefill(p, spec: MLASpec, x, positions, max_len: int):
     y, latent = mla_full(p, spec, x, positions, return_latent=True)
     cache = init_mla_cache(spec, b, max_len, x.dtype)
     cache["latent"] = jax.lax.dynamic_update_slice(
-        cache["latent"], latent.astype(x.dtype), (0, 0, 0))
+        cache["latent"], latent.astype(x.dtype).transpose(0, 2, 1)[:, None],
+        (0, 0, 0, 0))
     return y, cache
-
-
-def _decode_block(t: int) -> int:
-    """A decode kernel block that divides ``t`` slots, so the latent slab
-    is not padded."""
-    return next((bk for bk in (512, 256, 128) if t % bk == 0), 512)
 
 
 def mla_decode(p, spec: MLASpec, x, pos, cache: dict, layer=None):
     """One-token MLA decode in the matmul-absorbed order: wkv_b's key half
     is folded into the query and its value half into the output, so K/V are
     never re-expanded for the whole cache; the attention runs through the
-    decode kernel under use_flash. ``layer`` is as in ``attn_decode``: with
-    it, the new latent row is written in place into the layer-stacked
-    cache."""
+    decode kernel under use_flash, with the latent cache as K = V. ``layer``
+    is as in ``attn_decode``: with it, the new latent slot column is written
+    in place into the layer-stacked cache and read from it there."""
     b = x.shape[0]
     h, lr = spec.n_heads, spec.kv_lora_rank
     positions = jnp.full((b, 1), pos, jnp.int32)
     q_nope, q_rope = _mla_q(p, spec, x, positions)            # (B,1,H,*)
     row = _mla_latent(p, spec, x, positions)                  # (B,1,L+R)
-    new_cache = {"latent": cache_put(cache["latent"], row, (0, pos, 0),
-                                     layer)}
-    latent = cache_slab(new_cache["latent"], layer)           # (B,T,L+R)
+    new_cache = {"latent": cache_put(cache["latent"],
+                                     row.transpose(0, 2, 1)[:, None],
+                                     (0, 0, 0, pos), layer)}
 
-    t = latent.shape[1]
+    t = new_cache["latent"].shape[-1]
     valid = jnp.arange(t, dtype=jnp.int32) <= pos
     scale = mla_softmax_scale(spec)
     wkv_b = p["wkv_b"].reshape(lr, h, spec.qk_nope_dim + spec.v_head_dim)
@@ -480,16 +488,17 @@ def mla_decode(p, spec: MLASpec, x, pos, cache: dict, layer=None):
                                 axis=-1)                       # (B,1,H,L+R)
     if FLAGS["use_flash"]:
         from repro.kernels.decode_attention import ops as dec_ops
-        kv = latent[:, :, None, :]                             # one kv head
-        ctx = dec_ops.decode_attention(q_cat, kv, kv, valid, scale=scale,
-                                       block_kv=_decode_block(t))
+        ctx = dec_ops.decode_attention(q_cat, new_cache["latent"],
+                                       new_cache["latent"], valid, layer,
+                                       scale=scale)
         ctx = ctx[..., :lr]
     else:
-        scores = jnp.einsum("bqhc,btc->bhqt", q_cat, latent)
+        latent = cache_slab(new_cache["latent"], layer)[:, 0]  # (B,L+R,T)
+        scores = jnp.einsum("bqhc,bct->bhqt", q_cat, latent)
         scores = scores.astype(jnp.float32) * scale
         scores = jnp.where(valid[None, None, None, :], scores, -1e30)
         w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum("bhqt,btl->bqhl", w, latent[..., :lr])
+        ctx = jnp.einsum("bhqt,blt->bqhl", w, latent[:, :lr])
     with jax.named_scope("mla_absorb"):
         out = jnp.einsum("bqhl,lhv->bqhv", ctx.astype(x.dtype), w_v)
     y = out.reshape(b, 1, -1) @ p["wo"]

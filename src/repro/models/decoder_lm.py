@@ -15,10 +15,11 @@ dicts; for count > 1 every leaf gains a leading (count,) axis. Caches mirror
 that layout, so they shard with NamedSharding like parameters. Decode scans a
 count > 1 segment with its stacked caches in the scan's carry and the layer
 index beside the stacked params in its xs: an attention or MLA layer writes
-its new row in place into the stacked arrays and reads its slab from them,
-and a recurrent layer writes its new state slab back, so no layer's cache is
-sliced out and restacked whole. The carry keeps the layout the caches enter
-the step in, so the donated cache is updated where it lies.
+its new slot column in place into the stacked arrays, held slots minor, and
+the decode kernel reads the layer's K/V from them by that index; a recurrent
+layer writes its new state slab back. So no layer's cache is sliced out and
+restacked whole. The carry keeps the layout the caches enter the step in, so
+the donated cache is updated where it lies.
 
 A served MoE layer (``mlp.moe_dropless``) also returns counts of its work,
 int32 scalars; the layer and block functions return them beside the caches
@@ -430,10 +431,11 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None,
 
 def _default_layout(x):
     """``x`` held to the layout the default device gives an array of its
-    shape: the layout the caches enter and leave the step in. On a TPU, XLA
-    would otherwise give the decode scan's carry the layout its body prefers
-    (the decode kernel's, head dim minor) and copy every stacked cache whole
-    into and out of the loop."""
+    shape: the layout the caches enter and leave the step in. On a TPU that
+    is row-major for the slots-minor caches, the layout the decode kernel
+    reads; without the constraint XLA may give the decode scan's carry
+    another layout its body prefers and copy every stacked cache whole into
+    and out of the loop."""
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         return x

@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention import ref as flash_ref
+from repro.kernels.decode_attention import kernel as decode_k
 from repro.kernels.decode_attention import ops as dec_ops
 from repro.kernels.decode_attention import ref as dec_ref
 from repro.kernels.gcn_spmm import ops as spmm_ops
@@ -70,7 +71,7 @@ def test_flash_matches_model_attention():
 # decode attention
 # ---------------------------------------------------------------------------
 DEC_CASES = [
-    # (B, T, H, KV, D, n_valid, dtype)
+    # (B, T, H, KV, D, n_valid, dtype); k/v (B, KV, D, T), slots minor
     (1, 256, 4, 4, 64, 200, jnp.float32),
     (2, 512, 8, 2, 64, 512, jnp.float32),
     (1, 384, 4, 1, 128, 100, jnp.float32),     # MQA, non-pow2 T
@@ -78,17 +79,65 @@ DEC_CASES = [
 ]
 
 
+def _check_decode(q, k, v, valid, layer=None, scale=None):
+    """The wrapper (blocks from the shapes) and the kernel at 128-slot
+    blocks, one kv head a step, against the reference."""
+    want = np.asarray(dec_ref.decode_attention_ref(q, k, v, valid, layer,
+                                                   scale=scale), np.float32)
+    got = dec_ops.decode_attention(q, k, v, valid, layer, scale=scale)
+    b, _, h, d = q.shape
+    kvh = k.shape[-3]
+    stacked = k if layer is not None else k[None]
+    blocked = decode_k.decode_attention_grouped(
+        q.reshape(b, kvh, h // kvh, d), stacked,
+        v if layer is not None else v[None],
+        (valid > 0).astype(jnp.int32)[None],
+        jnp.full((1,), 0 if layer is None else layer, jnp.int32),
+        block_kv=128, scale=scale).reshape(b, 1, h, d)
+    for out in (got, blocked):
+        np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                                   **_tol(q.dtype))
+
+
 @pytest.mark.parametrize("b,t,h,kv,d,nv,dtype", DEC_CASES)
 def test_decode_attention_vs_ref(b, t, h, kv, d, nv, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (b, 1, h, d), dtype)
-    k = jax.random.normal(ks[1], (b, t, kv, d), dtype)
-    v = jax.random.normal(ks[2], (b, t, kv, d), dtype)
+    k = jax.random.normal(ks[1], (b, kv, d, t), dtype)
+    v = jax.random.normal(ks[2], (b, kv, d, t), dtype)
     valid = (jnp.arange(t) < nv).astype(jnp.int32)
-    got = dec_ops.decode_attention(q, k, v, valid, block_kv=128)
-    want = dec_ref.decode_attention_ref(q, k, v, valid)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **_tol(dtype))
+    _check_decode(q, k, v, valid)
+
+
+def _ring_valid(t):
+    """A wrapped ring cache's mask: slots 50..119 stale, the rest live."""
+    s = jnp.arange(t)
+    return ((s < 50) | (s >= 120)).astype(jnp.int32)
+
+
+STACKED_CASES = {
+    # name: (L, layer, B, T, H, KV, D, valid(T), K = V, scale)
+    "first_layer": (3, 0, 2, 256, 8, 2, 64, lambda t: jnp.arange(t) < 200,
+                    False, None),
+    "last_layer": (3, 2, 2, 256, 8, 2, 64, lambda t: jnp.arange(t) < 200,
+                   False, None),
+    "ring_mask": (3, 1, 2, 256, 4, 4, 32, _ring_valid, False, None),
+    "mla_shaped": (3, 1, 1, 384, 16, 1, 576, lambda t: jnp.arange(t) < 300,
+                   True, 0.07),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACKED_CASES))
+def test_decode_attention_stacked_read(case):
+    """The kernel reads layer ``layer`` of a layer-stacked (L, B, KV, D, T)
+    cache in place, by index."""
+    n, layer, b, t, h, kv, d, valid_fn, shared, scale = STACKED_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (n, b, kv, d, t), jnp.float32)
+    v = k if shared else jax.random.normal(ks[2], k.shape, jnp.float32)
+    _check_decode(q, k, v, valid_fn(t).astype(jnp.int32), jnp.int32(layer),
+                  scale)
 
 
 def test_decode_matches_full_last_position():
@@ -100,7 +149,8 @@ def test_decode_matches_full_last_position():
     v = jax.random.normal(ks[2], (b, s, kv, d), jnp.float32)
     full = flash_ref.attention_ref(q, k, v)
     valid = jnp.ones((s,), jnp.int32)
-    got = dec_ops.decode_attention(q[:, -1:], k, v, valid)
+    got = dec_ops.decode_attention(q[:, -1:], k.transpose(0, 2, 3, 1),
+                                   v.transpose(0, 2, 3, 1), valid)
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(full[:, -1]),
                                rtol=1e-5, atol=1e-5)
 

@@ -10,6 +10,7 @@ worker collects the same tests and only the worker given this file loads the
 TPU library. Keep every such compile in this one file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,14 +87,17 @@ def test_flash_prefill_compiles_at_phi3_widths(one_chip):
 
 
 def test_grouped_decode_compiles_at_phi3_widths(one_chip):
-    b, kvh, g, t, d = 4, 32, 1, 2048, 96
+    """The kernel reads layer 1 of a two-layer stacked cache held slots
+    minor, (L, B, KV, D, T)."""
+    n, b, kvh, g, t, d = 2, 4, 32, 1, 2048, 96
     q = _spec((b, kvh, g, d), jnp.bfloat16, one_chip)
-    kv = _spec((b, kvh, t, d), jnp.bfloat16, one_chip)
+    kv = _spec((n, b, kvh, d, t), jnp.bfloat16, one_chip)
     valid = _spec((1, t), jnp.int32, one_chip)
+    layer = _spec((1,), jnp.int32, one_chip)
     text = _compiled_text(
-        lambda q, k, v, m: decode_k.decode_attention_grouped(
-            q, k, v, m, block_kv=decode_k.DEFAULT_BLOCK_KV, interpret=False),
-        q, kv, kv, valid)
+        lambda q, k, v, m, i: decode_k.decode_attention_grouped(
+            q, k, v, m, i, interpret=False),
+        q, kv, kv, valid, layer)
     assert "tpu_custom_call" in text
 
 
@@ -119,13 +123,58 @@ def test_train_step_grad_compiles_at_phi3_widths(one_chip, monkeypatch):
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 
+def _check_stacked_read(text: str, stacked):
+    """The compiled decode step reads the layer-stacked cache ``stacked``
+    (a ShapeDtypeStruct) where it lies. Nothing copies it whole; no op makes
+    a one-layer slab of it (a bf16 array of the stacked shape's sizes less
+    the layer axis, in any order, with or without unit axes); nothing is
+    padded under ``jit(decode_attention)``; and every decode kernel call
+    takes the stacked array itself as K and V."""
+    shape = "bf16[" + ",".join(map(str, stacked.shape)) + "]"
+    lines = text.splitlines()
+    copies = [line for line in lines
+              if shape in line.split(" copy", 1)[0]
+              and (" copy(" in line or " copy-start(" in line)]
+    assert not copies, copies[:2]
+    one_layer = sorted(n for n in stacked.shape[1:] if n != 1)
+    slabs = [line for line in lines
+             for dims in re.findall(r"= bf16\[([\d,]+)\]", line)
+             if sorted(int(n) for n in dims.split(",") if n != "1")
+             == one_layer]
+    assert not slabs, slabs[:2]
+    pads = [line for line in lines
+            if " pad(" in line and "jit(decode_attention)" in line]
+    assert not pads, pads[:2]
+    calls = [line for line in lines if 'custom_call_target="tpu_custom_call"'
+             in line and "decode_attention" in line]
+    assert calls
+    assert all(line.split("custom-call(", 1)[1].count(shape) == 2
+               for line in calls), calls[:1]
+
+
+def _row_major_default(stacked, one_chip) -> bool:
+    """Whether the chip's default layout for ``stacked``'s shape is row-major:
+    then ``decoder_lm._default_layout`` holds the scan's carry in the layout
+    the decode kernel reads, and no copy enters the loop."""
+    from jax.experimental.layout import Layout
+    dev = next(iter(one_chip.device_set))
+    pjrt = dev.client.get_default_layout(stacked.dtype, stacked.shape, dev)
+    order = Layout.from_pjrt_layout(pjrt).major_to_minor
+    return tuple(order) == tuple(range(len(stacked.shape)))
+
+
 @pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "starcoder2-3b"])
 def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
                                                    arch):
-    """The serving decode step at real widths, four stacked layers, batch 8,
-    1280 cache slots, through the compiled decode kernel. The layer scan
-    carries the stacked caches in the layout they enter in, so nothing
-    copies a stacked cache whole into or out of the loop, and the step's
+    """The serving decode step at real widths and depth, batch 8, 1280 cache
+    slots, through the compiled decode kernel. (At four layers starcoder2's
+    stacked cache fits the chip's VMEM, and XLA stages it there whole for
+    the kernel: a copy the real depth does not have.) The caches are held
+    slots minor, (L, B, KV, D, T), whose default layout on the chip is
+    row-major; the layer scan carries them in that layout, each layer writes
+    its slot column in place, and the decode kernel reads the layer's K/V
+    out of the stacked arrays by index: nothing copies, slices, relays or
+    pads a cache between the scan's carry and the kernel, and the step's
     scratch memory stays below one stacked cache."""
     from repro.models import decoder_lm as dlm
     from repro.training.train_step import make_decode_step
@@ -133,8 +182,8 @@ def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
     monkeypatch.setattr(jax, "devices", lambda *a: list(one_chip.device_set))
     monkeypatch.setitem(cc.RUNTIME, "use_flash", True)
     cfg = get_config(arch)
-    cfg = dataclasses.replace(cfg, segments=(
-        Segment(count=4, layers=cfg.segments[0].layers),))
+    assert len(cfg.segments) == 1
+    n = cfg.segments[0].count
     place = lambda t: jax.tree.map(                               # noqa: E731
         lambda s: _spec(s.shape, s.dtype, one_chip), t)
     params = place(sp.param_struct(cfg))
@@ -146,11 +195,10 @@ def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     k = caches[0][0]["k"]
-    stacked = "bf16[" + ",".join(map(str, k.shape)) + "]"
-    copies = [line for line in text.splitlines()
-              if stacked in line.split(" copy", 1)[0]
-              and (" copy(" in line or " copy-start(" in line)]
-    assert not copies, copies[:2]
+    attn = cfg.segments[0].layers[0].attn
+    assert k.shape == (n, 8, attn.n_kv_heads, attn.head_dim, 1280)
+    assert _row_major_default(k, one_chip)
+    _check_stacked_read(text, k)
     assert compiled.memory_analysis().temp_size_in_bytes < k.size * 2
 
 
@@ -169,7 +217,9 @@ def _deepseek_share(count: int, moe: bool):
 def test_absorbed_mla_decode_step_compiles(one_chip, monkeypatch):
     """The absorbed MLA decode step at published widths (128 heads over one
     latent head of 576), two stacked layers, batch 32, 768 slots, through
-    the compiled decode kernel, writing each latent row in place."""
+    the compiled decode kernel: each latent slot column is written in place
+    into the stacked (L, B, 1, 576, T) cache, and the kernel reads it there
+    as K and V, with no slab, relayout or pad in between."""
     from repro.models import decoder_lm as dlm
     from repro.training.train_step import make_decode_step
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -186,12 +236,9 @@ def test_absorbed_mla_decode_step_compiles(one_chip, monkeypatch):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     latent = caches[0][0]["latent"]
-    assert latent.shape == (2, 32, 768, 576)
-    stacked = "bf16[" + ",".join(map(str, latent.shape)) + "]"
-    copies = [line for line in text.splitlines()
-              if stacked in line.split(" copy", 1)[0]
-              and (" copy(" in line or " copy-start(" in line)]
-    assert not copies, copies[:2]
+    assert latent.shape == (2, 32, 1, 576, 768)
+    assert _row_major_default(latent, one_chip)
+    _check_stacked_read(text, latent)
 
 
 @pytest.mark.parametrize("tokens", [32, 16384], ids=["decode", "prefill"])
