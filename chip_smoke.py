@@ -44,9 +44,9 @@ from repro.core.graph import paper_fleet46, random_fleet  # noqa: E402
 from repro.data.synthetic import SyntheticConfig, make_batch  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh_for  # noqa: E402
-from repro.launch.serve import serve_batch  # noqa: E402
+from repro.launch.serve import serve_batch, serving_runtime  # noqa: E402
 from repro.launch.train import train_loop  # noqa: E402
-from repro.models import common as cc  # noqa: E402
+from repro.models.common import Runtime, use_runtime  # noqa: E402
 from repro.models.registry import get_api  # noqa: E402
 from repro.training.train_step import make_decode_step, make_prefill  # noqa: E402
 
@@ -174,23 +174,25 @@ def planner_phase(sizes: PlannerSizes, seed: int) -> dict:
 # ---------------------------------------------------------------------------
 # Phase 2: serve
 # ---------------------------------------------------------------------------
-def _serve_logits(cfg, params, batch, gen, max_len, steps, use_flash):
+def _serve_logits(cfg, params, batch, gen, max_len, steps,
+                  runtime: Runtime):
     """Last-position prefill logits and the logits of the first ``steps``
-    decode steps, every step fed the token in ``gen``. Returns
-    (steps + 1, B, V) float32. Fresh closures, so nothing traced under the
-    other ``use_flash`` value is reused."""
+    decode steps, every step fed the token in ``gen``, traced under
+    ``runtime``. Returns (steps + 1, B, V) float32. Fresh closures, so
+    nothing traced under another runtime is reused."""
     api = get_api(cfg)
-    cc.RUNTIME["use_flash"] = use_flash
-    last, caches = jax.jit(make_prefill(cfg, api), static_argnums=(2,))(
-        params, batch, max_len)
-    decode = jax.jit(lambda p, t, pos, c: api.decode_step(p, cfg, t, pos, c),
-                     donate_argnums=(3,))
-    s = batch["tokens"].shape[1]
-    rows = [np.asarray(last[:, -1], np.float32)]
-    for i in range(steps):
-        logits, caches = decode(params, jnp.asarray(gen[:, i:i + 1]),
-                                jnp.int32(s + i), caches)
-        rows.append(np.asarray(logits[:, -1], np.float32))
+    with use_runtime(runtime):
+        last, caches = jax.jit(make_prefill(cfg, api), static_argnums=(2,))(
+            params, batch, max_len)
+        decode = jax.jit(
+            lambda p, t, pos, c: api.decode_step(p, cfg, t, pos, c),
+            donate_argnums=(3,))
+        s = batch["tokens"].shape[1]
+        rows = [np.asarray(last[:, -1], np.float32)]
+        for i in range(steps):
+            logits, caches = decode(params, jnp.asarray(gen[:, i:i + 1]),
+                                    jnp.int32(s + i), caches)
+            rows.append(np.asarray(logits[:, -1], np.float32))
     return np.stack(rows)
 
 
@@ -207,30 +209,27 @@ def serve_phase(cfg: ModelConfig, sizes: ServeSizes, seed: int) -> dict:
     _check(bool(((gen >= 0) & (gen < cfg.vocab_size)).all()),
            "generated token outside the vocabulary")
 
-    # the programs serve_batch ran, compiled again (the persistent cache
-    # holds them) to read their text
+    # the programs serve_batch ran on the chip, compiled again (the
+    # persistent cache holds them) to read their text
     max_len = sizes.prompt + sizes.gen
     prefill = make_prefill(cfg, api)
     caches = jax.eval_shape(lambda p, b: prefill(p, b, max_len),
                             params, batch)[1]
     token = jax.ShapeDtypeStruct((sizes.batch, 1), jnp.int32)
     pos = jax.ShapeDtypeStruct((), jnp.int32)
-    kernels = {
-        "prefill": _has_kernel(jax.jit(prefill, static_argnums=(2,)),
-                               params, batch, max_len),
-        "decode": _has_kernel(jax.jit(make_decode_step(cfg, api),
-                                      donate_argnums=(3,)),
-                              params, token, pos, caches),
-    }
+    with use_runtime(serving_runtime("tpu")):
+        kernels = {
+            "prefill": _has_kernel(jax.jit(prefill, static_argnums=(2,)),
+                                   params, batch, max_len),
+            "decode": _has_kernel(jax.jit(make_decode_step(cfg, api),
+                                          donate_argnums=(3,)),
+                                  params, token, pos, caches),
+        }
 
-    flash = cc.RUNTIME["use_flash"]
-    try:
-        kern = _serve_logits(cfg, params, batch, gen, max_len,
-                             sizes.compare_steps, use_flash=True)
-        xla = _serve_logits(cfg, params, batch, gen, max_len,
-                            sizes.compare_steps, use_flash=False)
-    finally:
-        cc.RUNTIME["use_flash"] = flash
+    kern = _serve_logits(cfg, params, batch, gen, max_len,
+                         sizes.compare_steps, Runtime(kernels=True))
+    xla = _serve_logits(cfg, params, batch, gen, max_len,
+                        sizes.compare_steps, Runtime(kernels=False))
     _check(np.isfinite(kern).all() and np.isfinite(xla).all(),
            "serve logits not finite")
     # bounded relative to the logits' spread, per row (prefill, then each

@@ -132,14 +132,13 @@ class ModelConfig:
     # vlm: frontend patch-embedding dim (stub provides them precomputed)
     vit_dim: int = 0
     n_patches: int = 256
-    # runtime knobs
+    # how the model's own blocks run (what the launcher picks per backend
+    # and shape is models.common.Runtime)
     remat: bool = True
-    scan_segments: bool = True
     moe_seq_chunk: int = 0               # chunk tokens through MoE (0 = off)
     ce_chunk: int = 0                    # seq-chunked CE loss (0 = off):
                                          # never materializes (B,S,V) logits
     sub_quadratic: bool = False          # arch supports long_500k decode
-    logits_fp32: bool = True
 
     @property
     def n_layers(self) -> int:

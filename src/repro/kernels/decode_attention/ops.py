@@ -7,18 +7,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import kernel as _k
-from repro.kernels.decode_attention import ref as _ref
 
 
-@functools.partial(jax.jit, static_argnames=("force_ref", "scale"))
-def decode_attention(q, k, v, valid, layer=None, *, force_ref: bool = False,
+@functools.partial(jax.jit, static_argnames=("scale",))
+def decode_attention(q, k, v, valid, layer=None, *,
                      scale: float | None = None):
     """Model layout: q (B, 1, H, D); k/v (B, KV, D, T), slots minor, or
     layer-stacked (L, B, KV, D, T) and read in place at ``layer`` (int32
     scalar); valid (T,) bool/int. Returns (B, 1, H, D). ``scale``
     multiplies the scores (default D^-0.5)."""
-    if force_ref:
-        return _ref.decode_attention_ref(q, k, v, valid, layer, scale=scale)
     if layer is None:
         k, v, layer = k[None], v[None], 0
     b, _, h, d = q.shape

@@ -1,5 +1,5 @@
 """jit wrapper: layout conversion, padding to block multiples, backend
-selection (Pallas on TPU / interpret elsewhere / jnp reference fallback)."""
+selection (Pallas on TPU / interpret elsewhere)."""
 from __future__ import annotations
 
 import functools
@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import kernel as _k
-from repro.kernels.flash_attention import ref as _ref
 
 
 def _pad_to(x, axis, mult):
@@ -22,16 +21,13 @@ def _pad_to(x, axis, mult):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_kv", "force_ref", "scale"))
+                                             "block_kv", "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     block_q: int = _k.DEFAULT_BLOCK_Q,
                     block_kv: int = _k.DEFAULT_BLOCK_KV,
-                    force_ref: bool = False, scale: float | None = None):
+                    scale: float | None = None):
     """Public API — model layout: q (B, S, H, D); k/v (B, T, KV, D).
     ``scale`` multiplies the scores (default D^-0.5)."""
-    if force_ref:
-        return _ref.attention_ref(q, k, v, causal=causal, window=window,
-                                  scale=scale)
     b, s, h, d = q.shape
     t = k.shape[1]
     bq = min(block_q, max(8, 1 << (s - 1).bit_length()))

@@ -1,20 +1,15 @@
 """jit wrapper: pad fleet-sized graphs to MXU tiles, backend selection."""
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.gcn_spmm import kernel as _k
-from repro.kernels.gcn_spmm import ref as _ref
 
 
-@functools.partial(jax.jit, static_argnames=("force_ref",))
-def spmm(adj, feats, *, force_ref: bool = False):
+@jax.jit
+def spmm(adj, feats):
     """adj (N, N) @ feats (N, D) -> (N, D), any N/D (padded internally)."""
-    if force_ref:
-        return _ref.spmm_ref(adj, feats)
     n, d = feats.shape
     bi = min(_k.DEFAULT_BLOCK_I, max(8, 1 << (n - 1).bit_length()))
     bk = min(_k.DEFAULT_BLOCK_K, max(8, 1 << (n - 1).bit_length()))
@@ -28,15 +23,13 @@ def spmm(adj, feats, *, force_ref: bool = False):
     return o[:adj.shape[0], :d]
 
 
-@functools.partial(jax.jit, static_argnames=("force_ref",))
-def scaled_spmm(adj, feats, row_scale, col_scale, *, force_ref: bool = False):
+@jax.jit
+def scaled_spmm(adj, feats, row_scale, col_scale):
     """(diag(row_scale) @ adj @ diag(col_scale)) @ feats -> (M, D) in one
     fused masked-aggregate op (degree / Kipf-Welling normalization rides
     inside the kernel). adj (M, N), feats (N, D), row_scale (M,),
     col_scale (N,); any shapes (padded internally, scales padded with 0 so
     padding rows/cols are inert)."""
-    if force_ref:
-        return _ref.scaled_spmm_ref(adj, feats, row_scale, col_scale)
     n, d = feats.shape
     bi = min(_k.DEFAULT_BLOCK_I, max(8, 1 << (n - 1).bit_length()))
     bk = min(_k.DEFAULT_BLOCK_K, max(8, 1 << (n - 1).bit_length()))

@@ -21,6 +21,7 @@ Usage:
   python -m repro.launch.dryrun --arch all --shape all --multi-pod --out d/
 """
 import argparse
+import dataclasses
 import json
 import sys
 import time
@@ -50,14 +51,6 @@ from repro.training.train_step import (make_decode_step, make_prefill,
 _SERVE_FSDP_BYTES = 8e9 * 16   # 8 GB/device x model axis
 
 
-def _knob_defaults(args) -> dict:
-    return {
-        "q_chunk": args.q_chunk,
-        "ssm_chunk": args.ssm_chunk,
-        "mlstm_chunk": args.mlstm_chunk,
-    }
-
-
 def _ns_tree(mesh, spec_tree):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
@@ -75,7 +68,7 @@ def _mem_dict(mem) -> dict:
     return out
 
 
-def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
+def run_cell(arch: str, shape_name: str, multi_pod: bool, runtime: cc.Runtime,
              opt_overrides: dict | None = None, verbose: bool = True,
              save_hlo: str = "") -> dict:
     cfg = get_config(arch)
@@ -93,7 +86,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = int(np.prod(list(mesh.shape.values())))
-    cc.RUNTIME.update(knobs)
 
     t0 = time.time()
     api = get_api(cfg)
@@ -117,7 +109,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
         step = make_train_step(cfg, opt_cfg, api)
         cc.push_logical_rules(activation_resolver(rules))
         try:
-            with mesh:
+            with mesh, cc.use_runtime(runtime):
                 jitted = jax.jit(step, in_shardings=(state_sh, batch_sh),
                                  out_shardings=(state_sh, None),
                                  donate_argnums=(0,))
@@ -141,7 +133,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
         max_len = shape.seq_len + (cfg.n_patches if cfg.family == "vlm" else 0)
         cc.push_logical_rules(activation_resolver(rules))
         try:
-            with mesh:
+            with mesh, cc.use_runtime(runtime):
                 jitted = jax.jit(prefill_fn,
                                  in_shardings=(params_sh, batch_sh),
                                  static_argnums=(2,))
@@ -171,7 +163,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
         serve = make_decode_step(cfg, api)
         cc.push_logical_rules(activation_resolver(rules))
         try:
-            with mesh:
+            with mesh, cc.use_runtime(runtime):
                 jitted = jax.jit(
                     serve,
                     in_shardings=(params_sh, token_sh, pos_sh, caches_sh),
@@ -211,7 +203,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, knobs: dict,
                  "bytes_by_kind": loop_cost.get("bytes_by_kind", {})},
         "collectives": coll,
         "roofline": roof,
-        "knobs": dict(knobs),
+        "runtime": dataclasses.asdict(runtime),
     })
     if verbose:
         print(f"== {arch} x {shape_name} on {result['mesh']} ==")
@@ -233,15 +225,13 @@ def main(argv=None) -> int:
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--out", default="")
-    ap.add_argument("--q-chunk", type=int, default=256)
-    ap.add_argument("--ssm-chunk", type=int, default=256)
-    ap.add_argument("--mlstm-chunk", type=int, default=256)
+    ap.add_argument("--seq-chunk", type=int, default=256)
     args = ap.parse_args(argv)
 
     archs = ARCHS if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]
     meshes = [False, True] if args.both_meshes else [args.multi_pod]
-    knobs = _knob_defaults(args)
+    runtime = cc.Runtime(seq_chunk=args.seq_chunk)
 
     results = []
     n_fail = 0
@@ -249,7 +239,7 @@ def main(argv=None) -> int:
         for shape_name in shapes:
             for mp in meshes:
                 try:
-                    r = run_cell(arch, shape_name, mp, knobs)
+                    r = run_cell(arch, shape_name, mp, runtime)
                 except Exception as e:  # a cell failure is a bug — surface it
                     traceback.print_exc()
                     r = {"arch": arch, "shape": shape_name,

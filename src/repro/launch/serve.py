@@ -1,6 +1,6 @@
 """Serving launcher — batched prefill + greedy decode over the registry API.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \
+  PYTHONPATH=src python -m repro.launch.serve --arch phi3-mini-3.8b --smoke \
       --batch 4 --prompt-len 32 --gen 16
 """
 from __future__ import annotations
@@ -17,35 +17,40 @@ from jax.profiler import TraceAnnotation
 from repro.configs import get_config, reduce_for_smoke
 from repro.data.synthetic import SyntheticConfig, make_batch
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models.common import Runtime, use_runtime
 from repro.models.decoder_lm import has_moe
 from repro.models.registry import get_api
 from repro.training.train_step import make_decode_step, make_prefill
 
 
-def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
+def serving_runtime(backend: str) -> Runtime:
+    """The serve path's runtime: attention through the Pallas flash and
+    decode kernels on a TPU."""
+    return Runtime(kernels=backend == "tpu")
+
+
+def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print, *,
+                runtime: Runtime | None = None):
     """Prefill the prompt batch, then greedy-decode gen_tokens. Returns
-    (generated (B, gen), stats dict).
+    (generated (B, gen), stats dict). Both programs are traced under
+    ``runtime`` (default: ``serving_runtime`` of the backend), unset after.
 
     The decode step donates its KV-cache argument, so every step writes the
     new token into the prefill-time allocation instead of allocating a fresh
     cache pytree per token (the caches dominate serving memory:
-    B x max_len x layers). ``stats`` is machine-readable so harnesses
-    (benchmarks/serve_bench.py) can calibrate simulated replica costs from a
-    real measured decode rate instead of parsing log lines. ``decode_s`` is
-    ``dispatch_s``, the host's loop over the decode steps, plus ``drain_s``,
-    the wait for the device to finish them. A decoder LM with MoE layers
-    adds their counts (``models.mlp.moe_dropless``), each as
-    ``<name>_prefill`` and ``<name>_decode``: summed over layers by the
-    step programs, over decode steps on the device, and fetched once, at
-    gather.
+    B x max_len x layers). ``stats`` is machine-readable, so harnesses
+    (``chipbench/drivers/serve.py``) read the measured rates instead of
+    parsing log lines. ``decode_s`` is ``dispatch_s``, the host's loop over
+    the decode steps, plus ``drain_s``, the wait for the device to finish
+    them. A decoder LM with MoE layers adds their counts
+    (``models.mlp.moe_dropless``), each as ``<name>_prefill`` and
+    ``<name>_decode``: summed over layers by the step programs, over decode
+    steps on the device, and fetched once, at gather.
 
     Four profiler annotations, ``launch.serve.prefill``,
     ``launch.serve.decode.dispatch``, ``launch.serve.decode.drain`` and
     ``launch.serve.gather``, mark the phases in a profiler's trace on the
     device's clock; with no profiler collecting they cost a check each."""
-    if jax.default_backend() == "tpu":
-        from repro.models import common as cc
-        cc.RUNTIME["use_flash"] = True   # Pallas flash/decode kernels
     api = get_api(cfg)
     counted = cfg.family not in ("audio", "vlm") and has_moe(cfg)
     prefill_fn = make_prefill(cfg, api, counts=counted)
@@ -56,25 +61,26 @@ def serve_batch(cfg, params, batch: dict, gen_tokens: int, log=print):
     extra = cfg.n_patches if cfg.family == "vlm" else 0
     max_len = extra + s + gen_tokens
 
-    t0 = time.perf_counter()
-    with TraceAnnotation("launch.serve.prefill"):
-        last_logits, caches, *counts = jax.jit(
-            prefill_fn, static_argnums=(2,))(params, batch, max_len)
-        token = jnp.argmax(last_logits[:, -1], axis=-1).astype(
-            jnp.int32)[:, None]
-        jax.block_until_ready(token)
-    t_prefill = time.perf_counter() - t0
+    with use_runtime(runtime or serving_runtime(jax.default_backend())):
+        t0 = time.perf_counter()
+        with TraceAnnotation("launch.serve.prefill"):
+            last_logits, caches, *counts = jax.jit(
+                prefill_fn, static_argnums=(2,))(params, batch, max_len)
+            token = jnp.argmax(last_logits[:, -1], axis=-1).astype(
+                jnp.int32)[:, None]
+            jax.block_until_ready(token)
+        t_prefill = time.perf_counter() - t0
 
-    out = [token]
-    totals = [jax.tree.map(jnp.zeros_like, c) for c in counts]
-    t0 = time.perf_counter()
-    with TraceAnnotation("launch.serve.decode.dispatch"):
-        for i in range(gen_tokens - 1):
-            pos = jnp.int32(extra + s + i)
-            token, caches, *totals = decode_fn(params, token, pos, caches,
-                                               *totals)
-            out.append(token)
-    t1 = time.perf_counter()
+        out = [token]
+        totals = [jax.tree.map(jnp.zeros_like, c) for c in counts]
+        t0 = time.perf_counter()
+        with TraceAnnotation("launch.serve.decode.dispatch"):
+            for i in range(gen_tokens - 1):
+                pos = jnp.int32(extra + s + i)
+                token, caches, *totals = decode_fn(params, token, pos, caches,
+                                                   *totals)
+                out.append(token)
+        t1 = time.perf_counter()
     with TraceAnnotation("launch.serve.decode.drain"):
         jax.block_until_ready(token)
     t2 = time.perf_counter()

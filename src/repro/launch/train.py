@@ -35,12 +35,10 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 
 
-def train_runtime(seq_len: int) -> dict:
-    """Execution knobs (``models.common.RUNTIME``) for the train step."""
-    knobs = {"use_flash": False}   # the flash kernel has no backward pass
-    if seq_len > 512:
-        knobs.update(q_chunk=256, ssm_chunk=256, mlstm_chunk=256)
-    return knobs
+def train_runtime(seq_len: int) -> cc.Runtime:
+    """The train step's runtime: XLA attention (the flash kernel has no
+    backward pass), in blocks of 256 positions past 512."""
+    return cc.Runtime(kernels=False, seq_chunk=256 if seq_len > 512 else 0)
 
 
 def train_loop(cfg, steps: int, global_batch: int, seq_len: int,
@@ -58,7 +56,6 @@ def train_loop(cfg, steps: int, global_batch: int, seq_len: int,
     if mesh is None:
         mesh = make_mesh_for(len(jax.devices()))
     rules = ShardingRules(mesh=mesh, fsdp=mesh.size > 1)
-    cc.RUNTIME.update(train_runtime(seq_len))
 
     state = init_train_state(cfg, jax.random.PRNGKey(seed), opt_cfg)
     state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -85,21 +82,22 @@ def train_loop(cfg, steps: int, global_batch: int, seq_len: int,
     cc.push_logical_rules(activation_resolver(rules))
     history = []
     try:
-        t0 = time.time()
-        for step, batch in data.iter(start_step):
-            if step >= steps:
-                break
-            jb = {k: jnp.asarray(v) for k, v in batch.items()}
-            state, metrics = jitted(state, jb)
-            if step % log_every == 0 or step == steps - 1:
-                m = {k: float(v) for k, v in metrics.items()}
-                m["step"] = step
-                m["elapsed_s"] = round(time.time() - t0, 1)
-                history.append(m)
-                log(f"step {step:5d} loss {m['loss']:.4f} "
-                    f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.2f}")
-            if mgr and (step + 1) % ckpt_every == 0:
-                mgr.save(step + 1, state, extra={"data_step": step + 1})
+        with cc.use_runtime(train_runtime(seq_len)):   # traced in here
+            t0 = time.time()
+            for step, batch in data.iter(start_step):
+                if step >= steps:
+                    break
+                jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                state, metrics = jitted(state, jb)
+                if step % log_every == 0 or step == steps - 1:
+                    m = {k: float(v) for k, v in metrics.items()}
+                    m["step"] = step
+                    m["elapsed_s"] = round(time.time() - t0, 1)
+                    history.append(m)
+                    log(f"step {step:5d} loss {m['loss']:.4f} "
+                        f"lr {m['lr']:.2e} gnorm {m['grad_norm']:.2f}")
+                if mgr and (step + 1) % ckpt_every == 0:
+                    mgr.save(step + 1, state, extra={"data_step": step + 1})
         if mgr:
             mgr.save(steps, state, extra={"data_step": steps})
     finally:

@@ -2,7 +2,7 @@
 
 Two execution paths per layer:
   * full-sequence (train / prefill) — optionally routed through the Pallas
-    flash-attention kernel (FLAGS["use_flash"], TPU target);
+    flash-attention kernel (``common.Runtime.kernels``);
   * single-token decode against a KV cache — full cache, ring (sliding-window)
     cache, or MLA compressed cache (absorbed matmul order). Decode caches
     hold their slots minor, the layout the decode kernel reads in place.
@@ -23,8 +23,6 @@ from repro.configs.base import AttnSpec, MLASpec
 from repro.models import common as cc
 from repro.models.common import (apply_norm, apply_rope, causal_mask,
                                  dense_init, logical_constraint)
-
-from repro.models.common import RUNTIME as FLAGS  # launcher-set knobs
 
 
 # ---------------------------------------------------------------------------
@@ -121,12 +119,13 @@ def attn_full(p, spec: AttnSpec, x, positions, return_kv: bool = False):
     q = logical_constraint(q, cc.BATCH, None, cc.HEADS, None)
     k = logical_constraint(k, cc.BATCH, None, cc.HEADS, None)
     v = logical_constraint(v, cc.BATCH, None, cc.HEADS, None)
-    q_chunk = FLAGS["q_chunk"]
-    if FLAGS["use_flash"] and spec.causal:
+    rt = cc.runtime()
+    chunk = rt.seq_chunk
+    if rt.kernels and spec.causal:
         from repro.kernels.flash_attention import ops as flash_ops
         out = flash_ops.flash_attention(q, k, v, window=spec.window)
-    elif q_chunk and s % q_chunk == 0 and s > q_chunk:
-        out = _chunked_attend(q, k, v, spec, q_chunk)
+    elif chunk and s % chunk == 0 and s > chunk:
+        out = _chunked_attend(q, k, v, spec, chunk)
     else:
         if spec.causal:
             mask = causal_mask(positions, positions, spec.window)
@@ -248,7 +247,7 @@ def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
         slot_pos = cache_slab(new_cache["slot_pos"], layer)
         valid = (slot_pos >= 0) & (slot_pos <= pos) & (slot_pos > pos - spec.window)
 
-    if FLAGS["use_flash"]:
+    if cc.runtime().kernels:
         from repro.kernels.decode_attention import ops as dec_ops
         out = dec_ops.decode_attention(q, new_cache["k"], new_cache["v"],
                                        valid, layer)
@@ -265,7 +264,7 @@ def attn_decode(p, spec: AttnSpec, x, pos, cache: dict, layer=None):
 #
 # The cache holds one latent row per token, c_kv ‖ k_rope (kv_lora_rank +
 # qk_rope_dim), as one kv head with its slots minor, (B, 1, L + R, T).
-# Prefill expands it per head and, under use_flash, runs the flash kernel
+# Prefill expands it per head and, with Runtime.kernels, runs the flash kernel
 # over q_nope ‖ q_rope against k_nope ‖ k_rope with v padded to the same
 # width. Absorbed decode folds wkv_b's key half into the query and
 # its value half into the output, so the decode kernel attends 128 query
@@ -421,11 +420,12 @@ def mla_full(p, spec: MLASpec, x, positions, return_latent: bool = False):
     k_nope = logical_constraint(k_nope, cc.BATCH, None, cc.HEADS, None)
     v = logical_constraint(v, cc.BATCH, None, cc.HEADS, None)
     scale = mla_softmax_scale(spec)
-    q_chunk = FLAGS["q_chunk"]
-    if FLAGS["use_flash"]:
+    rt = cc.runtime()
+    chunk = rt.seq_chunk
+    if rt.kernels:
         out = _mla_flash(q_nope, q_rope, k_nope, k_rope, v, scale)
-    elif q_chunk and s % q_chunk == 0 and s > q_chunk:
-        out = _mla_chunked(q_nope, q_rope, k_nope, k_rope, v, scale, q_chunk,
+    elif chunk and s % chunk == 0 and s > chunk:
+        out = _mla_chunked(q_nope, q_rope, k_nope, k_rope, v, scale, chunk,
                            x.dtype)
     else:
         scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
@@ -463,7 +463,7 @@ def mla_decode(p, spec: MLASpec, x, pos, cache: dict, layer=None):
     """One-token MLA decode in the matmul-absorbed order: wkv_b's key half
     is folded into the query and its value half into the output, so K/V are
     never re-expanded for the whole cache; the attention runs through the
-    decode kernel under use_flash, with the latent cache as K = V. ``layer``
+    decode kernel with ``Runtime.kernels``, with the latent cache as K = V. ``layer``
     is as in ``attn_decode``: with it, the new latent slot column is written
     in place into the layer-stacked cache and read from it there."""
     b = x.shape[0]
@@ -486,7 +486,7 @@ def mla_decode(p, spec: MLASpec, x, pos, cache: dict, layer=None):
         q_eff = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_k)     # (B,1,H,L)
         q_cat = jnp.concatenate([q_eff, q_rope.astype(q_eff.dtype)],
                                 axis=-1)                       # (B,1,H,L+R)
-    if FLAGS["use_flash"]:
+    if cc.runtime().kernels:
         from repro.kernels.decode_attention import ops as dec_ops
         ctx = dec_ops.decode_attention(q_cat, new_cache["latent"],
                                        new_cache["latent"], valid, layer,

@@ -1,5 +1,5 @@
 """Shared layer primitives: inits, norms, activations, RoPE, logical sharding
-annotations.
+annotations, and the ``Runtime`` the layers are traced under.
 
 Parameters are plain nested dicts of jnp arrays. Activation sharding hints use
 ``logical_constraint`` with *logical axis names*; parallel/sharding.py resolves
@@ -7,6 +7,9 @@ them against the active mesh (and drops non-divisible axes).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import dataclasses
 from typing import Optional
 
 import jax
@@ -24,15 +27,33 @@ VOCAB = "act_vocab"
 
 _MESH_RULES_STACK: list = []
 
-# Runtime execution knobs, set by the launcher per (backend, shape):
-#   use_flash    — route attention through the Pallas kernels (TPU)
-#   q_chunk      — flash-style q-block chunking for attention/MLA in pure
-#                  XLA (the shardable dry-run path; 0 = full quadratic)
-#   ssm_chunk    — chunkwise Mamba scan (bounds associative-scan live set)
-#   mlstm_chunk  — chunkwise-recurrent mLSTM (bounds the quadratic form)
-RUNTIME = {"use_flash": False, "q_chunk": 0, "ssm_chunk": 0,
-           "mlstm_chunk": 0, "moe_chunk": 0, "remat_policy": "",
-           "moe_combine_bf16": False, "moe_capacity_factor": 0.0}
+
+@dataclasses.dataclass(frozen=True)
+class Runtime:
+    """How the layers run, chosen by a launcher per backend and shape and read
+    with ``runtime()`` while it traces. ``kernels``: attention through the
+    Pallas flash and decode kernels (interpreted off a TPU). ``seq_chunk``:
+    the sequence block of XLA attention and MLA, the Mamba scan and the mLSTM
+    (0 = the whole sequence)."""
+    kernels: bool = False
+    seq_chunk: int = 0
+
+
+_CURRENT = contextvars.ContextVar("model_runtime", default=Runtime())
+
+
+def runtime() -> Runtime:
+    return _CURRENT.get()
+
+
+@contextlib.contextmanager
+def use_runtime(rt: Runtime):
+    """Trace under ``rt`` within the block; the previous runtime after it."""
+    token = _CURRENT.set(rt)
+    try:
+        yield rt
+    finally:
+        _CURRENT.reset(token)
 
 
 def push_logical_rules(rules):

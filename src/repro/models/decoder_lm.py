@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import LayerSpec, ModelConfig, Segment
@@ -163,7 +162,7 @@ def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
                 y, cache = xlstm_mod.slstm_prefill(p["slstm"], layer.xlstm, h)
             else:
                 y = xlstm_mod.slstm_full(p["slstm"], layer.xlstm, h)
-        x = x + checkpoint_name(y, "block_out")
+        x = x + y
     aux = jnp.zeros((), jnp.float32)
     if layer.mlp in ("dense", "moe"):
         with jax.named_scope("norm"):
@@ -178,7 +177,7 @@ def layer_full(p, layer: LayerSpec, cfg: ModelConfig, x, positions,
             else:
                 y2, aux = mlp_mod.moe(p["moe"], layer.moe, h2, cfg.act,
                                       seq_chunk=cfg.moe_seq_chunk)
-            x = x + checkpoint_name(y2, "block_out")
+            x = x + y2
     x = logical_constraint(x, cc.BATCH, cc.SEQ, cc.EMBED)
     return x, aux, cache, counts
 
@@ -298,14 +297,6 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> list:
 def _maybe_remat(fn, cfg: ModelConfig):
     if not cfg.remat:
         return fn
-    # "outputs": save the attention/MLP block outputs (checkpoint_name'd
-    # below) so the backward pass does not recompute them — trades a few GB
-    # of seq-sharded bf16 saves for ~the forward's HBM traffic (SSPerf I4).
-    policy = cc.RUNTIME.get("remat_policy", "") or "nothing"
-    if policy == "outputs":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.save_only_these_names(
-                "block_out"))
     return jax.checkpoint(fn,
                           policy=jax.checkpoint_policies.nothing_saveable)
 
@@ -342,9 +333,7 @@ def backbone_full(params, cfg: ModelConfig, x, positions,
 
 def _logits(params, cfg: ModelConfig, x):
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(x.dtype)
-    if cfg.logits_fp32:
-        logits = logits.astype(jnp.float32)
+    logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
     return logical_constraint(logits, cc.BATCH, None, cc.VOCAB)
 
 

@@ -131,11 +131,7 @@ def _route(router_w, x, spec: MoESpec, capacity: int):
                                           dtype=jnp.float32), axis=1)
     aux = e * jnp.mean(jnp.sum(me * ce / spec.top_k, axis=-1))
 
-    # bf16 routing tensors halve the dominant (G,n,E,C) HBM traffic; gate
-    # weights are in [0,1] so bf16's 0.4% relative error is routing-benign
-    # (SSPerf deepseek I6; default stays f32 — knob for the perf runs).
-    rdt = jnp.bfloat16 if cc.RUNTIME.get("moe_combine_bf16") else jnp.float32
-    combine = jnp.zeros((g_, n, e, capacity), rdt)
+    combine = jnp.zeros((g_, n, e, capacity), jnp.float32)
     prev_counts = jnp.zeros((g_, e), jnp.int32)
     for k in range(spec.top_k):
         mask_k = jax.nn.one_hot(gate_idx[:, :, k], e, dtype=jnp.int32)
@@ -144,9 +140,9 @@ def _route(router_w, x, spec: MoESpec, capacity: int):
         keep = (pos_k < capacity) & (mask_k > 0)
         # keep the per-k routing tensors expert-sharded (the (G,n,E,C)
         # one-hots dominate MoE HBM traffic when replicated over `model`)
-        pos_oh = jax.nn.one_hot(pos_k, capacity, dtype=rdt)
+        pos_oh = jax.nn.one_hot(pos_k, capacity, dtype=jnp.float32)
         pos_oh = logical_constraint(pos_oh, cc.BATCH, None, cc.EXPERT, None)
-        combine = combine + (gate_vals[:, :, k, None, None].astype(rdt)
+        combine = combine + (gate_vals[:, :, k, None, None]
                              * keep[..., None] * pos_oh)
         combine = logical_constraint(combine, cc.BATCH, None, cc.EXPERT,
                                      None)
@@ -170,9 +166,9 @@ def moe(p, spec: MoESpec, x, act: str, seq_chunk: int = 0):
     """x: (B, S, d) -> (y, aux_loss): the capacity path, for training and
     dry-runs (serving takes ``moe_dropless``).
 
-    Scalable path (seq_chunk set): groups = batch rows,
-    lax.scan over seq chunks with a rematerialized body — per-step live
-    dispatch is (B, chunk, E, C) with per-group capacity C = chunk·k/E·cf.
+    Scalable path (seq_chunk set): groups = batch rows, lax.scan over seq
+    chunks with a rematerialized body — per-step live dispatch is
+    (B, chunk, E, C) with per-group capacity C = chunk·k/E·capacity_factor.
     The batch dim keeps the data sharding; experts ride the model axis, so
     the dispatch einsum's token contraction becomes the expected
     reduce-scatter/all-to-all under SPMD."""
@@ -181,13 +177,11 @@ def moe(p, spec: MoESpec, x, act: str, seq_chunk: int = 0):
     if spec.held != spec.n_experts:
         raise ValueError("the capacity path holds every expert; a share of "
                          "them is served by moe_dropless")
-    # launcher/perf-iteration overrides (0 = use the config's values)
-    seq_chunk = cc.RUNTIME.get("moe_chunk", 0) or seq_chunk
-    cf = cc.RUNTIME.get("moe_capacity_factor", 0.0) or spec.capacity_factor
 
     if seq_chunk and s % seq_chunk == 0 and s > seq_chunk:
         n_chunks = s // seq_chunk
-        cap = max(1, int(seq_chunk * spec.top_k / spec.n_experts * cf))
+        cap = max(1, int(seq_chunk * spec.top_k / spec.n_experts
+                         * spec.capacity_factor))
         xc = x.reshape(b, n_chunks, seq_chunk, d).transpose(1, 0, 2, 3)
 
         def body(carry, xi):                       # xi (B, chunk, d)

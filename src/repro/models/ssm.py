@@ -82,13 +82,13 @@ def _combine(e1, e2):
 def _scan_ssm(p, spec: MambaSpec, u):
     """Selective scan over u (B,S,di) -> (y_ssm fp32 (B,S,di), h_last).
 
-    With RUNTIME["ssm_chunk"] set, runs chunkwise: the (B,C,di,ds)
+    With ``Runtime.seq_chunk`` set, runs chunkwise: the (B,C,di,ds)
     discretized tensors live one chunk at a time (lax.scan over chunks,
     rematerialized) instead of (B,S,di,ds) at once — this is what lets the
     4k/32k shapes lower within HBM. Chunked == full exactly (the recurrence
     composes associatively)."""
     b, s, di = u.shape
-    chunk = cc.RUNTIME["ssm_chunk"]
+    chunk = cc.runtime().seq_chunk
     if not chunk or s <= chunk or s % chunk != 0:
         da, dbu, c_mat = _ssm_params(p, spec, u)
         hs = jax.lax.associative_scan(_combine, (da, dbu), axis=1)[1]

@@ -163,7 +163,7 @@ def _mlstm_inner(p, spec: XLSTMSpec, x, want_state: bool):
     x_main, z = jnp.split(up, 2, axis=-1)
     conv_out = _causal_conv(p["conv_w"], p["conv_b"], x_main)
     q, k, v, i_pre, f_pre = _qkv_gates(p, spec, x_main, conv_out)
-    chunk = cc.RUNTIME["mlstm_chunk"]
+    chunk = cc.runtime().seq_chunk
     state = None
     if chunk and s > chunk and s % chunk == 0:
         h, state = _mlstm_chunkwise(q, k, v, i_pre, f_pre, chunk)
@@ -193,7 +193,8 @@ def _mlstm_inner(p, spec: XLSTMSpec, x, want_state: bool):
 
 
 def mlstm_full(p, spec: XLSTMSpec, x):
-    """Parallel stabilized form (chunkwise when RUNTIME asks). x: (B,S,d)."""
+    """Parallel stabilized form (chunkwise with ``Runtime.seq_chunk``).
+    x: (B,S,d)."""
     y, _ = _mlstm_inner(p, spec, x, want_state=False)
     return y
 

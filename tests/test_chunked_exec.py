@@ -8,16 +8,9 @@ import pytest
 
 from repro.configs.base import AttnSpec, MLASpec, MambaSpec, XLSTMSpec
 from repro.models import attention as attn_mod
-from repro.models import common as cc
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
-
-
-@pytest.fixture(autouse=True)
-def _reset_runtime():
-    saved = dict(cc.RUNTIME)
-    yield
-    cc.RUNTIME.update(saved)
+from repro.models.common import Runtime, use_runtime
 
 
 def _positions(b, s):
@@ -30,10 +23,9 @@ def test_chunked_attention_matches_full(window):
     spec = AttnSpec(n_heads=4, n_kv_heads=2, head_dim=16, window=window)
     p = attn_mod.init_attn(jax.random.PRNGKey(0), spec, d, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (b, s, d))
-    cc.RUNTIME["q_chunk"] = 0
     y_full = attn_mod.attn_full(p, spec, x, _positions(b, s))
-    cc.RUNTIME["q_chunk"] = 32
-    y_chunk = attn_mod.attn_full(p, spec, x, _positions(b, s))
+    with use_runtime(Runtime(seq_chunk=32)):
+        y_chunk = attn_mod.attn_full(p, spec, x, _positions(b, s))
     np.testing.assert_allclose(np.asarray(y_chunk), np.asarray(y_full),
                                rtol=2e-5, atol=2e-5)
 
@@ -44,12 +36,12 @@ def test_chunked_attention_grad_matches():
     p = attn_mod.init_attn(jax.random.PRNGKey(2), spec, d, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(3), (b, s, d))
 
-    def loss(p, chunk):
-        cc.RUNTIME["q_chunk"] = chunk
+    def loss(p):
         return jnp.sum(attn_mod.attn_full(p, spec, x, _positions(b, s)) ** 2)
 
-    g_full = jax.grad(loss)(p, 0)
-    g_chunk = jax.grad(loss)(p, 16)
+    g_full = jax.grad(loss)(p)
+    with use_runtime(Runtime(seq_chunk=16)):
+        g_chunk = jax.grad(loss)(p)
     for a, b_ in zip(jax.tree.leaves(g_full), jax.tree.leaves(g_chunk)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=1e-5)
@@ -61,10 +53,9 @@ def test_chunked_mla_matches_full():
                    qk_rope_dim=8, v_head_dim=8)
     p = attn_mod.init_mla(jax.random.PRNGKey(4), spec, d, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(5), (b, s, d))
-    cc.RUNTIME["q_chunk"] = 0
     y_full = attn_mod.mla_full(p, spec, x, _positions(b, s))
-    cc.RUNTIME["q_chunk"] = 32
-    y_chunk = attn_mod.mla_full(p, spec, x, _positions(b, s))
+    with use_runtime(Runtime(seq_chunk=32)):
+        y_chunk = attn_mod.mla_full(p, spec, x, _positions(b, s))
     np.testing.assert_allclose(np.asarray(y_chunk), np.asarray(y_full),
                                rtol=2e-5, atol=2e-5)
 
@@ -74,12 +65,11 @@ def test_chunkwise_mamba_matches_full():
     spec = MambaSpec(d_state=8)
     p = ssm_mod.init_mamba(jax.random.PRNGKey(6), spec, d, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(7), (b, s, d))
-    cc.RUNTIME["ssm_chunk"] = 0
     y_full = ssm_mod.mamba_full(p, spec, x)
     _, cache_full = ssm_mod.mamba_prefill(p, spec, x)
-    cc.RUNTIME["ssm_chunk"] = 16
-    y_chunk = ssm_mod.mamba_full(p, spec, x)
-    _, cache_chunk = ssm_mod.mamba_prefill(p, spec, x)
+    with use_runtime(Runtime(seq_chunk=16)):
+        y_chunk = ssm_mod.mamba_full(p, spec, x)
+        _, cache_chunk = ssm_mod.mamba_prefill(p, spec, x)
     np.testing.assert_allclose(np.asarray(y_chunk), np.asarray(y_full),
                                rtol=5e-5, atol=5e-5)
     np.testing.assert_allclose(np.asarray(cache_chunk["h"]),
@@ -92,12 +82,11 @@ def test_chunkwise_mlstm_matches_full():
     spec = XLSTMSpec(n_heads=2, proj_factor=2.0, conv_width=4)
     p = xlstm_mod.init_mlstm(jax.random.PRNGKey(8), spec, d, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(9), (b, s, d))
-    cc.RUNTIME["mlstm_chunk"] = 0
     y_full = xlstm_mod.mlstm_full(p, spec, x)
     _, cache_full = xlstm_mod.mlstm_prefill(p, spec, x)
-    cc.RUNTIME["mlstm_chunk"] = 16
-    y_chunk = xlstm_mod.mlstm_full(p, spec, x)
-    _, cache_chunk = xlstm_mod.mlstm_prefill(p, spec, x)
+    with use_runtime(Runtime(seq_chunk=16)):
+        y_chunk = xlstm_mod.mlstm_full(p, spec, x)
+        _, cache_chunk = xlstm_mod.mlstm_prefill(p, spec, x)
     np.testing.assert_allclose(np.asarray(y_chunk), np.asarray(y_full),
                                rtol=5e-5, atol=5e-5)
     for key in ("c", "n", "m"):
@@ -112,10 +101,9 @@ def test_chunkwise_mlstm_state_feeds_decode():
     spec = XLSTMSpec(n_heads=2, proj_factor=2.0, conv_width=4)
     p = xlstm_mod.init_mlstm(jax.random.PRNGKey(10), spec, d, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(11), (b, s + 1, d))
-    cc.RUNTIME["mlstm_chunk"] = 16
-    _, cache = xlstm_mod.mlstm_prefill(p, spec, x[:, :s])
-    y_dec, _ = xlstm_mod.mlstm_decode(p, spec, x[:, s:], cache)
-    cc.RUNTIME["mlstm_chunk"] = 0
+    with use_runtime(Runtime(seq_chunk=16)):
+        _, cache = xlstm_mod.mlstm_prefill(p, spec, x[:, :s])
+        y_dec, _ = xlstm_mod.mlstm_decode(p, spec, x[:, s:], cache)
     y_full = xlstm_mod.mlstm_full(p, spec, x)
     np.testing.assert_allclose(np.asarray(y_dec[:, 0]),
                                np.asarray(y_full[:, -1]),

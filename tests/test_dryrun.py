@@ -12,7 +12,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import sys, json
 sys.path.insert(0, r"%(src)s")
 from repro.launch.dryrun import run_cell
-knobs = {"q_chunk": 256, "ssm_chunk": 256, "mlstm_chunk": 256}
+from repro.models.common import Runtime
+runtime = Runtime(seq_chunk=256)
 out = []
 # one train cell on the single-pod mesh, one decode cell on the multi-pod
 # mesh, one audio prefill (covers the three lowering paths + cache specs)
@@ -20,7 +21,7 @@ for arch, shape, mp in [("xlstm-125m", "train_4k", False),
                         ("gemma3-1b", "decode_32k", True),
                         ("whisper-small", "prefill_32k", False),
                         ("qwen3-32b", "long_500k", False)]:
-    r = run_cell(arch, shape, mp, knobs, verbose=False)
+    r = run_cell(arch, shape, mp, runtime, verbose=False)
     out.append({k: r.get(k) for k in ("arch", "shape", "mesh", "ok",
                                       "skipped")})
 print("JSON:" + json.dumps(out))

@@ -51,6 +51,7 @@ def test_flash_matches_model_attention():
     """The kernel must agree with the model's einsum attention path."""
     from repro.configs.base import AttnSpec
     from repro.models import attention as attn_mod
+    from repro.models.common import Runtime, use_runtime
     spec = AttnSpec(n_heads=4, n_kv_heads=2, head_dim=32)
     b, s = 2, 64
     key = jax.random.PRNGKey(3)
@@ -58,11 +59,8 @@ def test_flash_matches_model_attention():
     x = jax.random.normal(key, (b, s, 64), jnp.float32)
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     y_ref = attn_mod.attn_full(p, spec, x, positions)
-    attn_mod.FLAGS["use_flash"] = True
-    try:
+    with use_runtime(Runtime(kernels=True)):
         y_flash = attn_mod.attn_full(p, spec, x, positions)
-    finally:
-        attn_mod.FLAGS["use_flash"] = False
     np.testing.assert_allclose(np.asarray(y_flash), np.asarray(y_ref),
                                rtol=2e-4, atol=2e-4)
 

@@ -27,6 +27,7 @@ from repro.models import attention as attn_mod  # noqa: E402
 from repro.models import common as cc  # noqa: E402
 from repro.models import decoder_lm as dlm  # noqa: E402
 from repro.models import mlp as mlp_mod  # noqa: E402
+from repro.models.common import Runtime, use_runtime  # noqa: E402
 
 SMALL = dict(
     name="deepseek-v2-small", hidden_size=64, num_hidden_layers=3,
@@ -56,14 +57,14 @@ def _moe_spec(**kw) -> MoESpec:
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["jnp", "interpret"])
-def test_prefill_then_decode_matches_reference(monkeypatch, kernels):
+def test_prefill_then_decode_matches_reference(kernels):
     """serve_batch's prefill and 8 decode steps serve the reference's best
     token at every position, the program's own logits along the served
     tokens match the reference's full forward, and the MoE pairs it counts
     are those the reference routes to the held experts; with ``kernels``
     prefill goes through the flash kernel and absorbed decode through the
     decode kernel, interpreted."""
-    monkeypatch.setitem(cc.RUNTIME, "use_flash", kernels)
+    rt = Runtime(kernels=kernels)
     cfg = drv.model_config(SMALL)
     spec = ref.Spec.from_config(SMALL)
     key = jax.random.PRNGKey(7)
@@ -71,20 +72,21 @@ def test_prefill_then_decode_matches_reference(monkeypatch, kernels):
     prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(8), (B, PROMPT),
                                            0, spec.vocab), np.int32)
     gen, stats = serve_batch(cfg, params, {"tokens": jnp.asarray(prompt)}, GEN,
-                             log=lambda *a: None)
+                             log=lambda *a: None, runtime=rt)
     tokens = np.concatenate([prompt, gen[:, :-1]], axis=1)
     with jax.default_matmul_precision("highest"):
         want, held = ref.forward(spec, key, tokens, PROMPT - 1)   # (B, GEN, V)
     np.testing.assert_array_equal(gen, want.argmax(-1))
 
-    logits, caches = dlm.prefill(params, cfg, tokens=jnp.asarray(prompt),
-                                 max_len=PROMPT + GEN)
-    got = [logits[:, -1]]
-    for i in range(GEN - 1):
-        logits, caches = dlm.decode_step(
-            params, cfg, jnp.asarray(gen[:, i:i + 1]),
-            jnp.int32(PROMPT + i), caches)
-        got.append(logits[:, -1])
+    with use_runtime(rt):
+        logits, caches = dlm.prefill(params, cfg, tokens=jnp.asarray(prompt),
+                                     max_len=PROMPT + GEN)
+        got = [logits[:, -1]]
+        for i in range(GEN - 1):
+            logits, caches = dlm.decode_step(
+                params, cfg, jnp.asarray(gen[:, i:i + 1]),
+                jnp.int32(PROMPT + i), caches)
+            got.append(logits[:, -1])
     np.testing.assert_allclose(np.stack(got, 1), want, rtol=2e-3, atol=2e-3)
 
     assert stats["moe_pairs_prefill"] == held[:, :PROMPT].sum() > 0

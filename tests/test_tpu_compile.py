@@ -23,8 +23,9 @@ from repro.kernels.decode_attention import kernel as decode_k
 from repro.kernels.flash_attention import kernel as flash_k
 from repro.kernels.gcn_spmm import kernel as spmm_k
 from repro.launch import specs as sp
+from repro.launch.serve import serving_runtime
 from repro.launch.train import train_runtime
-from repro.models import common as cc
+from repro.models.common import use_runtime
 from repro.training.optimizer import AdamWConfig
 from repro.training.train_step import make_train_step
 
@@ -102,13 +103,12 @@ def test_grouped_decode_compiles_at_phi3_widths(one_chip):
 
 
 def test_train_step_grad_compiles_at_phi3_widths(one_chip, monkeypatch):
-    """One phi3-width layer under ``jax.grad`` with the attention knobs
-    ``train_loop`` sets. The kernel wrappers are steered to the compiled
-    kernels, so a forward-only kernel on this path fails here at trace time."""
+    """One phi3-width layer under ``jax.grad`` with the runtime
+    ``train_loop`` traces under. The kernel wrappers are steered to the
+    compiled kernels, so a forward-only kernel on this path fails here at
+    trace time."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     seq_len = 1024
-    for knob, value in train_runtime(seq_len).items():
-        monkeypatch.setitem(cc.RUNTIME, knob, value)
     cfg = get_config("phi3-mini-3.8b")
     cfg = dataclasses.replace(cfg, segments=(
         Segment(count=1, layers=cfg.segments[0].layers),))
@@ -118,9 +118,10 @@ def test_train_step_grad_compiles_at_phi3_widths(one_chip, monkeypatch):
     state = place(sp.train_state_struct(cfg, opt))
     batch = place({"tokens": jax.ShapeDtypeStruct((1, seq_len), jnp.int32),
                    "labels": jax.ShapeDtypeStruct((1, seq_len), jnp.int32)})
-    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=(0,)).lower(
-        state, batch).compile()
-    assert compiled.memory_analysis().argument_size_in_bytes > 0
+    with use_runtime(train_runtime(seq_len)):
+        lowered = jax.jit(make_train_step(cfg, opt),
+                          donate_argnums=(0,)).lower(state, batch)
+    assert lowered.compile().memory_analysis().argument_size_in_bytes > 0
 
 
 def _check_stacked_read(text: str, stacked):
@@ -180,7 +181,6 @@ def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
     from repro.training.train_step import make_decode_step
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: list(one_chip.device_set))
-    monkeypatch.setitem(cc.RUNTIME, "use_flash", True)
     cfg = get_config(arch)
     assert len(cfg.segments) == 1
     n = cfg.segments[0].count
@@ -190,8 +190,10 @@ def test_decode_step_writes_stacked_cache_in_place(one_chip, monkeypatch,
     caches = place(jax.eval_shape(lambda: dlm.init_caches(cfg, 8, 1280)))
     token = _spec((8, 1), jnp.int32, one_chip)
     pos = _spec((), jnp.int32, one_chip)
-    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(3,)).lower(
-        params, token, pos, caches).compile()
+    with use_runtime(serving_runtime("tpu")):
+        lowered = jax.jit(make_decode_step(cfg), donate_argnums=(3,)).lower(
+            params, token, pos, caches)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     k = caches[0][0]["k"]
@@ -224,15 +226,16 @@ def test_absorbed_mla_decode_step_compiles(one_chip, monkeypatch):
     from repro.training.train_step import make_decode_step
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: list(one_chip.device_set))
-    monkeypatch.setitem(cc.RUNTIME, "use_flash", True)
     cfg = _deepseek_share(2, moe=False)
     place = lambda t: jax.tree.map(                               # noqa: E731
         lambda s: _spec(s.shape, s.dtype, one_chip), t)
     params = place(sp.param_struct(cfg))
     caches = place(jax.eval_shape(lambda: dlm.init_caches(cfg, 32, 768)))
-    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(3,)).lower(
-        params, _spec((32, 1), jnp.int32, one_chip),
-        _spec((), jnp.int32, one_chip), caches).compile()
+    with use_runtime(serving_runtime("tpu")):
+        lowered = jax.jit(make_decode_step(cfg), donate_argnums=(3,)).lower(
+            params, _spec((32, 1), jnp.int32, one_chip),
+            _spec((), jnp.int32, one_chip), caches)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     latent = caches[0][0]["latent"]
